@@ -62,4 +62,9 @@ def main(sizes=DEFAULT_SIZES, iters=50):
 
 
 if __name__ == "__main__":
+    import pathlib
+
+    from repro.device import use_compile_cache
+
+    use_compile_cache(pathlib.Path(__file__).parents[1])
     main()

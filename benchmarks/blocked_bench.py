@@ -235,6 +235,11 @@ __all__ = ["main", "bench_case", "check_gates"]
 
 if __name__ == "__main__":
     import argparse
+    import pathlib
+
+    from repro.device import use_compile_cache
+
+    use_compile_cache(pathlib.Path(__file__).parents[1])
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--cases", type=int, nargs="+", metavar="N S",

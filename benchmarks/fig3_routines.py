@@ -101,4 +101,9 @@ def main(sizes=(2 ** 12, 2 ** 14, 2 ** 16, 2 ** 18)):
 
 
 if __name__ == "__main__":
+    import pathlib
+
+    from repro.device import use_compile_cache
+
+    use_compile_cache(pathlib.Path(__file__).parents[1])
     main()

@@ -364,6 +364,11 @@ __all__ = ["main", "bench_chain", "bench_loop_body", "check_gates"]
 
 if __name__ == "__main__":
     import argparse
+    import pathlib
+
+    from repro.device import use_compile_cache
+
+    use_compile_cache(pathlib.Path(__file__).parents[1])
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--sizes", type=int, nargs="+",

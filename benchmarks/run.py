@@ -87,6 +87,10 @@ def main(json_path=None) -> int:
 if __name__ == "__main__":
     import argparse
 
+    from repro.device import use_compile_cache
+
+    use_compile_cache(pathlib.Path(__file__).parents[1])
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", metavar="PATH",
                     help="persist all sections as a BENCH_*.json "

@@ -246,6 +246,11 @@ def main(sizes=DEFAULT_SIZES, max_iters=20, json_path=None):
 
 if __name__ == "__main__":
     import argparse
+    import pathlib
+
+    from repro.device import use_compile_cache
+
+    use_compile_cache(pathlib.Path(__file__).parents[1])
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--sizes", type=int, nargs="+",

@@ -24,7 +24,7 @@ import copy
 import dataclasses
 import json
 import pathlib
-from typing import Mapping, Optional, Tuple, Union
+from typing import Mapping, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -35,26 +35,14 @@ from repro.core import lowering, spec as spec_mod
 from repro.core.runtime import (Program, Results, _synth_matrix,
                                 _synth_vector)
 from repro.core.spec import LoopSpec, ProgramSpec, SpecError
+from repro.device import V5E, peaks
 from repro.solvers.driver import LoopProgram, SolverProgram, SolverResult
 from repro.tune import config as tile_config, store as tune_store
 
 from .builder import ProgramBuilder
 
-# Roofline hardware constants (TPU v5e, per chip) — fallback copies of
-# repro.launch.roofline's values. The import must stay lazy AND
-# guarded: repro.launch pulls in the model-serving stack, which needs
-# newer-jax sharding APIs (jax.sharding.AxisType) than the BLAS layer
-# requires and is unimportable under older jax.
-_PEAK_FLOPS = 197e12
-_HBM_BW = 819e9
-
-
-def _hw_constants() -> Tuple[float, float]:
-    try:
-        from repro.launch import roofline
-        return roofline.PEAK_FLOPS, roofline.HBM_BW
-    except ImportError:
-        return _PEAK_FLOPS, _HBM_BW
+# the cost model prices programs on one TPU v5e chip
+_CHIP = peaks(V5E)
 
 
 # ---------------------------------------------------------------------------
@@ -297,13 +285,11 @@ class CostReport:
 
     @property
     def t_compute(self) -> float:
-        peak, _ = _hw_constants()
-        return self.flops / peak
+        return self.flops / _CHIP.flops
 
     @property
     def t_memory(self) -> float:
-        _, bw = _hw_constants()
-        return self.bytes / bw
+        return self.bytes / _CHIP.hbm_bw
 
     @property
     def bound(self) -> str:
@@ -726,7 +712,7 @@ class Executable:
         iters = int(iters)
         if iters < 1:
             raise ValueError("profile: iters must be >= 1")
-        peak, bw = _hw_constants()
+        peak, bw = _CHIP.flops, _CHIP.hbm_bw
 
         def model_row(gr, calls):
             nbytes = gr["bytes_naive"] - (

@@ -47,9 +47,10 @@ import jax.numpy as jnp
 from repro import obs
 from repro.kernels import gemm as gemm_mod, gemv as gemv_mod, ops, \
     symv as symv_mod
-from repro.kernels.common import (LANES, as_2d, cdiv, default_interpret,
-                                  pad_to, pl, pltpu, smem_scalar_spec)
-from repro.kernels.dot import iamax_block
+from repro.kernels.common import (ACC_SHAPE, LANES, acc_add, as_2d, cdiv,
+                                  default_interpret, pad_to, pl, pltpu,
+                                  smem_scalar_spec)
+from repro.kernels.dot import iamax_block, iamax_update
 from repro.kernels.gemm import gemm_block
 from repro.kernels.gemv import gemv_block, gemvt_block
 from repro.kernels.symv import symv_block
@@ -230,17 +231,17 @@ def _red_ref_map(sig, r_refs, is_idx):
 def _red_out_specs(graph, sig, index_map):
     """(out_specs, out_shapes) for a signature's reduction outputs:
     index-carrying reductions accumulate into an (f32 max, int32
-    index) ref pair, plain sums keep one (1, 1) f32 accumulator."""
+    index) tile pair, plain sums into one f32 tile. Every tile is a
+    lane-dense `ACC_SHAPE` block holding the running value in each
+    element (the chip stores no scalars into VMEM)."""
     red_specs, red_shapes = [], []
     for k in sig.red_out_keys:
-        if graph.nodes[k[0]].rdef.index_reduction:
-            red_specs += [pl.BlockSpec((1, 1), index_map)] * 2
-            red_shapes += [jax.ShapeDtypeStruct((1, 1), jnp.float32),
-                           jax.ShapeDtypeStruct((1, 1), jnp.int32)]
-        else:
-            red_specs.append(pl.BlockSpec((1, 1), index_map))
-            red_shapes.append(
-                jax.ShapeDtypeStruct((1, 1), jnp.float32))
+        dtypes = ((jnp.float32, jnp.int32)
+                  if graph.nodes[k[0]].rdef.index_reduction
+                  else (jnp.float32,))
+        for dt in dtypes:
+            red_specs.append(pl.BlockSpec(ACC_SHAPE, index_map))
+            red_shapes.append(jax.ShapeDtypeStruct(ACC_SHAPE, dt))
     return red_specs, red_shapes
 
 
@@ -250,7 +251,8 @@ def _collect_results(graph, sig, outs, length, width=None):
     `(length, width)` tiles for a 2-D tiled group), columnwise
     reduction outputs un-pad to `width` columns, plain reductions get
     their `post` hook (nrm2's sqrt) applied, and index-carrying
-    reductions return the int32 index."""
+    reductions return the int32 index. A reduction tile holds its
+    value in every element; element [0, 0] is read."""
     results = {}
     for key, o in zip(sig.elt_out_keys, outs[:len(sig.elt_out_keys)]):
         if width is not None:
@@ -296,18 +298,6 @@ def _build_fused_kernel(graph: DataflowGraph, group: FusionGroup,
 
         red_refs = _red_ref_map(sig, r_refs, _is_idx)
 
-        if r_refs:
-            @pl.when(step == 0)
-            def _init():
-                for key in sig.red_out_keys:
-                    if _is_idx(key):
-                        m_ref, i_ref = red_refs[key]
-                        m_ref[0, 0] = -1.0   # any |x| >= 0 beats this
-                        i_ref[0, 0] = jnp.int32(0)
-                    else:
-                        (acc,) = red_refs[key]
-                        acc[...] = jnp.zeros_like(acc)
-
         env = {}
         for key, ref_ in zip(sig.vec_in_keys, v_refs):
             env[key] = ref_[...].astype(jnp.float32)
@@ -322,14 +312,9 @@ def _build_fused_kernel(graph: DataflowGraph, group: FusionGroup,
             ref_[...] = env[key].astype(out_dtype)
         for key in sig.red_out_keys:
             if _is_idx(key):
-                val, gidx = env[key]
-                m_ref, i_ref = red_refs[key]
-                better = val > m_ref[0, 0]
-                i_ref[0, 0] = jnp.where(better, gidx, i_ref[0, 0])
-                m_ref[0, 0] = jnp.where(better, val, m_ref[0, 0])
+                iamax_update(*red_refs[key], *env[key], step == 0)
             else:
-                (acc,) = red_refs[key]
-                acc[0, 0] += env[key]
+                acc_add(*red_refs[key], env[key], step == 0)
 
     return kernel
 
@@ -558,29 +543,12 @@ def _build_anchored_kernel(graph: DataflowGraph, group: FusionGroup,
             # reductions accumulate once per row block; the i == 0
             # select seeds them without a separate init step (the
             # single-step kernel just writes)
+            first = True if single else i == 0
             for key in sig.red_out_keys:
                 if _is_idx(key):
-                    val, gidx = fenv[key]
-                    m_ref, i_ref = red_refs[key]
-                    if single:
-                        i_ref[0, 0] = gidx
-                        m_ref[0, 0] = val
-                        continue
-                    prev_m = jnp.where(i == 0, jnp.float32(-1.0),
-                                       m_ref[0, 0])
-                    prev_i = jnp.where(i == 0, jnp.int32(0),
-                                       i_ref[0, 0])
-                    better = val > prev_m
-                    i_ref[0, 0] = jnp.where(better, gidx, prev_i)
-                    m_ref[0, 0] = jnp.where(better, val, prev_m)
+                    iamax_update(*red_refs[key], *fenv[key], first)
                 else:
-                    (r_ref,) = red_refs[key]
-                    if single:
-                        r_ref[0, 0] = fenv[key]
-                        continue
-                    prev = jnp.where(i == 0, jnp.float32(0.0),
-                                     r_ref[0, 0])
-                    r_ref[0, 0] = prev + fenv[key]
+                    acc_add(*red_refs[key], fenv[key], first)
 
         if single:
             _finish_body()
@@ -869,17 +837,12 @@ def _build_tiled_kernel(graph: DataflowGraph, group: FusionGroup,
                     continue
                 prev = jnp.where(i == 0, jnp.zeros_like(val), ref_[...])
                 ref_[...] = prev + val
+            first = True if single else (i == 0) & (jo == 0)
             for key in sig.red_out_keys:
                 if _is_idx(key):
                     raise NotImplementedError(
                         "index reductions cannot ride a tiled group")
-                (r_ref,) = red_refs[key]
-                if single:
-                    r_ref[0, 0] = fenv[key]
-                    continue
-                first = (i == 0) & (jo == 0)
-                prev = jnp.where(first, jnp.float32(0.0), r_ref[0, 0])
-                r_ref[0, 0] = prev + fenv[key]
+                acc_add(*red_refs[key], fenv[key], first)
 
         if single:
             _finish_body()

@@ -18,31 +18,25 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .common import (LANES, as_2d, cdiv, default_interpret, pl,
-                     smem_scalar_spec)
+from .common import (ACC_SHAPE, LANES, acc_add, as_2d, cdiv,
+                     default_interpret, pad_to, pl, smem_scalar_spec)
 
 DEFAULT_BLOCK_ROWS = 256
 
 
 def _axpydot_kernel(alpha_ref, w_ref, v_ref, u_ref, o_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
     # axpy stage: produce the z window in registers/VMEM (on-chip edge)
     z = w_ref[...].astype(jnp.float32) - alpha_ref[0] * v_ref[...].astype(
         jnp.float32)
     # dot stage: consume it immediately
-    o_ref[0, 0] += jnp.sum(z * u_ref[...].astype(jnp.float32))
+    acc_add(o_ref, jnp.sum(z * u_ref[...].astype(jnp.float32)),
+            pl.program_id(0) == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def axpydot(alpha, w, v, u, *, block_rows=DEFAULT_BLOCK_ROWS,
             interpret=None):
     interpret = default_interpret() if interpret is None else interpret
-    from .common import pad_to
     w2d, _ = as_2d(w)
     v2d, _ = as_2d(v)
     u2d, _ = as_2d(u)
@@ -55,8 +49,8 @@ def axpydot(alpha, w, v, u, *, block_rows=DEFAULT_BLOCK_ROWS,
         _axpydot_kernel,
         grid=(cdiv(rows, block_rows),),
         in_specs=[smem_scalar_spec(), vec_spec, vec_spec, vec_spec],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        out_specs=pl.BlockSpec(ACC_SHAPE, lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct(ACC_SHAPE, jnp.float32),
         interpret=interpret,
     )(jnp.reshape(alpha, (1,)).astype(jnp.float32), w2d, v2d, u2d)
     return out[0, 0]

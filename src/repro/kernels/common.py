@@ -15,7 +15,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "pl", "pltpu", "default_interpret", "pad_to", "cdiv",
-    "as_2d", "LANES", "SUBLANES", "smem_scalar_spec",
+    "as_2d", "LANES", "SUBLANES", "ACC_SHAPE", "acc_add", "mxu_dot",
+    "smem_scalar_spec",
 ]
 
 # TPU vector-register geometry: the VPU operates on (8, 128) f32 tiles,
@@ -24,14 +25,52 @@ __all__ = [
 LANES = 128
 SUBLANES = 8
 
+# Reductions accumulate into one lane-dense (8, 128) VMEM tile that
+# holds the running value in every element: the chip's compiler stores
+# vectors into VMEM, never scalars. Callers read element [0, 0] of the
+# finished tile outside the kernel.
+ACC_SHAPE = (SUBLANES, LANES)
+
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
 def default_interpret() -> bool:
-    """Interpret on anything that is not a real TPU."""
-    return jax.default_backend() != "tpu"
+    """Compile natively on a TPU and interpret on the CPU. Any other
+    backend raises: the kernels are written for the TPU only, and a
+    silent fall-back to the interpreter would hide the device."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on backend {backend!r}; use a "
+        f"TPU, or JAX_PLATFORMS=cpu for interpret mode")
+
+
+def acc_add(ref, value, first):
+    """Add a 0-d kernel value to every element of an `ACC_SHAPE`
+    accumulator tile. `first` (a traced bool, or Python `True` for a
+    single-step grid) seeds the tile with `value` instead."""
+    if first is True:
+        ref[...] = jnp.full(ref.shape, value, ref.dtype)
+        return
+    ref[...] = jnp.where(first, jnp.zeros_like(ref), ref[...]) + value
+
+
+def mxu_dot(a, b):
+    """`a @ b` on the MXU, accumulated in f32. Two bf16 operands
+    multiply exactly in one pass; anything else is taken in f32 at
+    full f32 precision. Mosaic's default for an f32 matmul is a single
+    bf16 pass, which leaves a relative error near 2e-3: a CG solve on
+    a v5e then stalls there however small its recurrence residual."""
+    if a.dtype == b.dtype == jnp.bfloat16:
+        return jnp.dot(a, b, preferred_element_type=jnp.float32)
+    return jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32),
+                   preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def pad_to(x: jax.Array, multiple: int, axis: int = 0, value=0):

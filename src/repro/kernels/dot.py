@@ -3,7 +3,9 @@
 Reductions accumulate across sequential grid steps into a single VMEM
 output block — the TPU grid is guaranteed sequential, which is what an
 AIE kernel iterating over incoming windows does on the paper's device.
-Accumulation is always f32 regardless of input dtype.
+Accumulation is always f32 regardless of input dtype. The block is an
+`ACC_SHAPE` tile with the running value in every element (the chip
+stores no scalars into VMEM); the caller reads element [0, 0].
 """
 from __future__ import annotations
 
@@ -12,42 +14,26 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .common import LANES, as_2d, cdiv, default_interpret, pl
+from .common import (ACC_SHAPE, LANES, acc_add, as_2d, cdiv,
+                     default_interpret, pad_to, pl)
 
 DEFAULT_BLOCK_ROWS = 256
 
 
 def _dot_kernel(x_ref, y_ref, o_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
     x = x_ref[...].astype(jnp.float32)
     y = y_ref[...].astype(jnp.float32)
-    o_ref[0, 0] += jnp.sum(x * y)
+    acc_add(o_ref, jnp.sum(x * y), pl.program_id(0) == 0)
 
 
 def _asum_kernel(x_ref, o_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[0, 0] += jnp.sum(jnp.abs(x_ref[...].astype(jnp.float32)))
+    acc_add(o_ref, jnp.sum(jnp.abs(x_ref[...].astype(jnp.float32))),
+            pl.program_id(0) == 0)
 
 
 def _sumsq_kernel(x_ref, o_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
     x = x_ref[...].astype(jnp.float32)
-    o_ref[0, 0] += jnp.sum(x * x)
+    acc_add(o_ref, jnp.sum(x * x), pl.program_id(0) == 0)
 
 
 def iamax_block(x, step):
@@ -70,26 +56,33 @@ def iamax_block(x, step):
     return local_max, step * (rows * lanes) + local_idx
 
 
+def iamax_update(m_ref, i_ref, local_max, gidx, first):
+    """Fold one block's (max |x|, index) pair into the running f32/int32
+    accumulator tiles. `first` (a traced bool, or Python `True` for a
+    single-step grid) seeds them instead; cross-block ties keep the
+    first occurrence via the strictly-greater compare."""
+    if first is True:
+        m_ref[...] = jnp.full(m_ref.shape, local_max, jnp.float32)
+        i_ref[...] = jnp.full(i_ref.shape, gidx, jnp.int32)
+        return
+    # any |x| >= 0 beats the -1 seed
+    prev_m = jnp.where(first, jnp.float32(-1.0), m_ref[...])
+    prev_i = jnp.where(first, jnp.int32(0), i_ref[...])
+    better = local_max > prev_m
+    i_ref[...] = jnp.where(better, gidx, prev_i)
+    m_ref[...] = jnp.where(better, local_max, prev_m)
+
+
 def _iamax_kernel(x_ref, m_ref, i_ref):
     """m = running max |x|, i = its flat index (separate f32/int32
-    accumulators); cross-block ties keep the first occurrence via the
-    strictly-greater compare."""
+    accumulator tiles)."""
     step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        m_ref[0, 0] = -1.0   # any |x| >= 0 beats the seed
-        i_ref[0, 0] = jnp.int32(0)
-
     local_max, gidx = iamax_block(x_ref[...], step)
-    better = local_max > m_ref[0, 0]
-    i_ref[0, 0] = jnp.where(better, gidx, i_ref[0, 0])
-    m_ref[0, 0] = jnp.where(better, local_max, m_ref[0, 0])
+    iamax_update(m_ref, i_ref, local_max, gidx, step == 0)
 
 
 def _reduce_call(kernel, vectors, *, block_rows, interpret,
                  out_shape=None):
-    from .common import pad_to
     x2ds = []
     for v in vectors:
         v2d, _ = as_2d(v)
@@ -104,7 +97,7 @@ def _reduce_call(kernel, vectors, *, block_rows, interpret,
     vec_spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     single = out_shape is None
     if single:
-        out_shape = [jax.ShapeDtypeStruct((1, 1), jnp.float32)]
+        out_shape = [jax.ShapeDtypeStruct(ACC_SHAPE, jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -148,6 +141,6 @@ def iamax(x, *, block_rows=DEFAULT_BLOCK_ROWS, interpret=None):
     interpret = default_interpret() if interpret is None else interpret
     _, idx = _reduce_call(
         _iamax_kernel, [x], block_rows=block_rows, interpret=interpret,
-        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)])
+        out_shape=[jax.ShapeDtypeStruct(ACC_SHAPE, jnp.float32),
+                   jax.ShapeDtypeStruct(ACC_SHAPE, jnp.int32)])
     return idx[0, 0]

@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .common import (cdiv, default_interpret, pad_to, pl, pltpu,
+from .common import (cdiv, default_interpret, mxu_dot, pad_to, pl, pltpu,
                      smem_scalar_spec)
 
 DEFAULT_BLOCK_M = 256
@@ -22,12 +22,10 @@ DEFAULT_BLOCK_K = 256
 
 def gemm_block(a_block, b_block):
     """f32 contribution of one (bm, bk) A window against its (bk, bn) B
-    window — one MXU pass. Factored out so the standalone kernel below
+    window on the MXU (`mxu_dot`). Factored out so the standalone kernel below
     and the tiled anchored-kernel generator (core.codegen) splice the
     exact same block body."""
-    return jnp.dot(a_block.astype(jnp.float32),
-                   b_block.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+    return mxu_dot(a_block, b_block)
 
 
 def _gemm_kernel(alpha_ref, beta_ref, a_ref, b_ref, c_ref, o_ref, acc_ref):

@@ -20,7 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .common import cdiv, default_interpret, pad_to, pl, smem_scalar_spec
+from .common import (cdiv, default_interpret, mxu_dot, pad_to, pl,
+                     smem_scalar_spec)
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 512
@@ -31,9 +32,7 @@ def gemv_block(a_block, x_block):
     window — the MXU inner product. Factored out so the standalone
     kernel below and the anchored fused-kernel generator
     (core.codegen) splice the exact same block body."""
-    return jnp.dot(a_block.astype(jnp.float32),
-                   x_block.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+    return mxu_dot(a_block, x_block)
 
 
 def _gemv_kernel(alpha_ref, beta_ref, a_ref, x_ref, y_ref, o_ref):
@@ -81,9 +80,7 @@ def gemvt_block(a_block, x_block):
     per A-row block, accumulating into a (bn, 1) output. Factored out
     for the same reason as `gemv_block`: the anchored fused-kernel
     generator splices this exact block body."""
-    return jnp.dot(a_block.astype(jnp.float32).T,
-                   x_block.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+    return mxu_dot(a_block.T, x_block)
 
 
 def _gemvt_kernel(alpha_ref, beta_ref, a_ref, x_ref, y_ref, o_ref):

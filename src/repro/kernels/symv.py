@@ -15,7 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .common import cdiv, default_interpret, pad_to, pl, smem_scalar_spec
+from .common import (cdiv, default_interpret, mxu_dot, pad_to, pl,
+                     smem_scalar_spec)
 
 DEFAULT_BLOCK = 256
 
@@ -33,8 +34,7 @@ def symv_block(a_block, mirror_block, x_block, i, j):
     r_ids = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
     c_ids = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
     a_sym = jnp.where(r_ids >= c_ids, a, mirror)
-    return jnp.dot(a_sym, x_block.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+    return mxu_dot(a_sym, x_block)
 
 
 def _symv_kernel(alpha_ref, beta_ref, a_ref, am_ref, x_ref, y_ref, o_ref):

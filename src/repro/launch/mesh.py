@@ -11,16 +11,10 @@ state (the dry-run must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-try:                               # jax >= 0.4.31
-    from jax.sharding import AxisType
-except ImportError:                # older jax: meshes are Auto-only
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

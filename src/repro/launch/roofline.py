@@ -20,12 +20,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
+from repro.device import V5E, peaks
+
 from . import hlo_cost
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
+# the dry-run meshes are TPU v5e pods
+_CHIP = peaks(V5E)
 
 
 @dataclasses.dataclass
@@ -41,15 +41,15 @@ class Roofline:
 
     @property
     def t_compute(self):
-        return self.flops / PEAK_FLOPS
+        return self.flops / _CHIP.flops
 
     @property
     def t_memory(self):
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / _CHIP.hbm_bw
 
     @property
     def t_collective(self):
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / _CHIP.ici_bw
 
     @property
     def t_bound(self):
@@ -57,8 +57,8 @@ class Roofline:
 
     @property
     def t_ideal(self):
-        t_c = (self.model_flops / self.chips) / PEAK_FLOPS
-        t_m = self.min_bytes / HBM_BW
+        t_c = (self.model_flops / self.chips) / _CHIP.flops
+        t_m = self.min_bytes / _CHIP.hbm_bw
         return max(t_c, t_m)
 
     @property
