@@ -1,0 +1,115 @@
+"""The kinds of request a traffic mix can send, and how each is checked
+against the plain reference.
+
+A traffic file (`bench/traffic/<mix>.json`) names its `kind` and the
+parameters below; the configuration's module (`bench/configs/
+<config>.py`) supplies the data, the plain reference and the work
+function. Nothing here knows a configuration by name.
+
+`calls`: one request is one call of the dataflow program `program`,
+  built by the configuration with the `repro.blas` builder and
+  compiled once by `repro.blas.compile`, on inputs cycled from a pool
+  of `pool` made at set-up. One client sends them with up to `ahead`
+  calls in flight (`bench.loop`). A sample of `sample` calls, drawn
+  from the seed, is checked against the float64 reference on the
+  host.
+  The configuration module gives `program(cfg, name)`, `inputs(cfg,
+  name, seed, pool)`, `reference(cfg, name, seed, pool_indices)`,
+  `work(cfg, name)`, `reference_program(cfg, name, precision)` and
+  `LIMITS`.
+
+`precision`, where given, puts the configuration's plain reference in
+the library's place, computed at that precision: `"highest"` is the
+configuration's own (float32), `"high"` the three-pass bfloat16
+product below it. The benchmark's runs never do; the control runs and
+the tests do.
+"""
+from __future__ import annotations
+
+import random
+import sys
+from typing import Optional
+
+import jax
+import numpy as np
+
+
+def _f64(a) -> np.ndarray:
+    return np.asarray(a, dtype=np.float64)
+
+
+def _reading(v: float) -> float:
+    """A reading to compare with its limit: a NaN or infinite one (a
+    poisoned answer) becomes the largest float, which fails any limit
+    and still prints as a JSON number."""
+    return float(v) if np.isfinite(v) else sys.float_info.max
+
+
+class Calls:
+    unit = "call"
+
+    def __init__(self, cfg, module, traffic, seed: int,
+                 precision: Optional[str] = None):
+        self.cfg, self.module, self.traffic = cfg, module, traffic
+        self.seed = seed
+        self.limits = module.LIMITS
+        self.program = traffic["program"]
+        self.ahead = int(traffic["ahead"])
+        self.fixed, self.pool = module.inputs(cfg, self.program, seed,
+                                              int(traffic["pool"]))
+        if precision is None:
+            from repro import blas
+            self.entry = blas.compile(module.program(cfg, self.program),
+                                      tiles="default").run
+        else:
+            self.entry = module.reference_program(cfg, self.program,
+                                                  precision)
+        jax.block_until_ready((self.fixed, self.pool))
+        self.size = int(traffic["sample"])
+        self._rng = random.Random(seed)
+        self.sample: list = []       # [(request, pool index, outputs)]
+        self.count = 0
+
+    def send(self, i: int):
+        return self.entry(**self.fixed, **self.pool[i % len(self.pool)])
+
+    @staticmethod
+    def wait(out) -> None:
+        jax.block_until_ready(list(out.values()))
+
+    @staticmethod
+    def ready(out) -> bool:
+        return all(v.is_ready() for v in out.values())
+
+    def keep(self, i: int, out) -> None:
+        """Reservoir sampling: every call of the run's windows is
+        equally likely to be among the `sample` checked."""
+        self.count += 1
+        item = (i, i % len(self.pool), out)
+        if len(self.sample) < self.size:
+            self.sample.append(item)
+            return
+        j = self._rng.randrange(self.count)
+        if j < self.size:
+            self.sample[j] = item
+
+    def work(self) -> tuple:
+        """(bytes, flops) that one call cannot do without."""
+        return self.module.work(self.cfg, self.program)
+
+    def check(self) -> tuple:
+        """({"err": worst ‖out − ref‖ / ‖ref‖}, failed calls), over
+        every output of every sampled call."""
+        want = self.module.reference(self.cfg, self.program, self.seed,
+                                     [k for _, k, _ in self.sample])
+        err, failed = 0.0, 0
+        for row, (_, _, out) in enumerate(self.sample):
+            e = max(_reading(np.linalg.norm(_f64(out[name]) - ref[row])
+                             / np.linalg.norm(ref[row]))
+                    for name, ref in want.items())
+            failed += int(e > self.limits["err"])
+            err = max(err, e)
+        return {"err": err}, failed
+
+
+KINDS = {"calls": Calls}
