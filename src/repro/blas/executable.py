@@ -24,6 +24,7 @@ import copy
 import dataclasses
 import json
 import pathlib
+import time
 from typing import Mapping, Optional, Union
 
 import jax
@@ -43,6 +44,12 @@ from .builder import ProgramBuilder
 
 # the cost model prices programs on one TPU v5e chip
 _CHIP = peaks(V5E)
+
+# the front door's calls and host seconds (`Executable.run`), and the
+# recording switch it reads inline on every call
+_RUNS = obs.aggregate("blas.run")
+_RECORDING = obs.get_registry()
+_clock = time.perf_counter
 
 
 # ---------------------------------------------------------------------------
@@ -434,23 +441,30 @@ class Executable:
 
         `tol` (and `axes` on batched()) are reserved keywords of this
         handle; a spec that names a public input or operand `tol` must
-        run through `Program`/`LoopProgram` directly."""
+        run through `Program`/`LoopProgram` directly.
+
+        Every call that returns adds its host seconds to the `blas.run`
+        aggregate of `repro.obs`, recording or not; with recording on
+        it is also a `blas.run` profiler annotation."""
+        t0 = _clock()
+        if _RECORDING.enabled:
+            with obs.annotate("blas.run", program=self.name,
+                              call=_RUNS.count):
+                out = self._run(tol, inputs)
+        elif self._jit_run is not None and tol is None:
+            out = Results(self._jit_run(inputs))    # a warm dataflow call
+        else:
+            out = self._run(tol, inputs)
+        _RUNS.add(_clock() - t0)
+        return out
+
+    def _run(self, tol: Optional[float], inputs: dict):
         if self.kind == "dataflow":
             if tol is not None:
                 raise TypeError(
                     "tol is a loop-program knob; this is a dataflow "
                     "program")
-            if self._jit_run is None:
-                # the jitted wrapper is memoized on the (digest-cached)
-                # IR, so every Executable of the same spec shares one
-                # trace/XLA compile, not one per handle
-                ir = self._impl.ir
-                fn = getattr(ir, "_jit_fn", None)
-                if fn is None:
-                    fn = jax.jit(ir.fn)
-                    ir._jit_fn = fn
-                self._jit_run = fn
-            return Results(self._jit_run(inputs))
+            return Results(self._jitted()(inputs))
         if isinstance(self._impl, LoopProgram):
             return self._impl.solve(tol=tol, **inputs)
         if tol is not None:
@@ -458,6 +472,30 @@ class Executable:
         return self._impl.solve(**inputs)
 
     __call__ = run
+
+    def _jitted(self):
+        """The dataflow program's jitted function. It is memoized on the
+        (digest-cached) IR, so every Executable of the same spec shares
+        one trace/XLA compile, not one per handle."""
+        if self._jit_run is None:
+            ir = self._impl.ir
+            fn = getattr(ir, "_jit_fn", None)
+            if fn is None:
+                fn = jax.jit(ir.fn)
+                ir._jit_fn = fn
+            self._jit_run = fn
+        return self._jit_run
+
+    def hlo_text(self, **inputs) -> str:
+        """The compiled HLO text of a dataflow program for these
+        inputs. Each op's `op_name` metadata holds its fusion group's
+        name scope (`<program>.g<i>`) and `pad` where it pads an
+        operand: the key that joins a profiler trace's device ops to
+        the program's layers (`bench/scopes.py`)."""
+        if self.kind != "dataflow":
+            raise TypeError(f"{self.name!r}: hlo_text() is for dataflow "
+                            f"programs")
+        return self._jitted().lower(inputs).compile().as_text()
 
     def one(self, *, tol: Optional[float] = None, **inputs) -> jax.Array:
         """Single-result sugar: the lone output of a one-output

@@ -60,6 +60,15 @@ from . import routines as R
 from .fusion import FusionGroup
 from .graph import DataflowGraph
 
+
+def group_key(program: str, gi: int) -> str:
+    """The stable name of fusion group `gi` of `program`: the name
+    scope of the group's ops (so their `op_name` reads
+    `.../mvt.g0/...`) and its generated kernel's name. The drift
+    report labels the group's row the same way."""
+    return f"{program}.g{gi}"
+
+
 # ---------------------------------------------------------------------------
 # Standalone dispatch (non-fused nodes)
 # ---------------------------------------------------------------------------
@@ -320,11 +329,12 @@ def _build_fused_kernel(graph: DataflowGraph, group: FusionGroup,
 
 
 def make_group_callable(graph: DataflowGraph, group: FusionGroup,
-                        dtype, *, interpret=None, tile_resolve=None):
+                        dtype, *, interpret=None, tile_resolve=None,
+                        name=None):
     """Returns fn(scalars: {(r,s): val}, vec_ins: {(r,p): 1-D array})
     -> {(r,p): value} for a fused group. `tile_resolve` is a
     `TilePlan.lookup` resolver overriding the group's block_rows per
-    shape bucket."""
+    shape bucket; `name` names the kernel (`group_key`)."""
     interpret = default_interpret() if interpret is None else interpret
     sig = _group_signature(graph, group)
     default_rows = max(graph.nodes[n].window_size for n in group.nodes)
@@ -353,6 +363,7 @@ def make_group_callable(graph: DataflowGraph, group: FusionGroup,
             out_specs=[vec_spec] * len(sig.elt_out_keys) + red_specs,
             out_shape=out_shapes,
             interpret=interpret,
+            name=name,
         ))
         calls[(rows, br)] = fn
         return fn
@@ -561,12 +572,14 @@ def _build_anchored_kernel(graph: DataflowGraph, group: FusionGroup,
 
 
 def make_anchored_callable(graph: DataflowGraph, group: FusionGroup,
-                           dtype, *, interpret=None, tile_resolve=None):
+                           dtype, *, interpret=None, tile_resolve=None,
+                           name=None):
     """Returns fn(scalars: {(r,s): val}, vec_ins: {(r,p): array}) ->
     {(r,p): value} for a level-2 anchored group. vec_ins carries the
     matrix operand under (anchor, A) alongside the vectors.
     `tile_resolve` is a `TilePlan.lookup` resolver overriding the
-    (bm, bn) matrix window per shape bucket."""
+    (bm, bn) matrix window per shape bucket; `name` names the kernel
+    (`group_key`)."""
     interpret = default_interpret() if interpret is None else interpret
     sig = _anchored_signature(graph, group)
     blas = graph.nodes[sig.anchor].blas
@@ -630,6 +643,7 @@ def make_anchored_callable(graph: DataflowGraph, group: FusionGroup,
             scratch_shapes=[] if kernel.single
             else [pltpu.VMEM((ob, 1), jnp.float32)],
             interpret=interpret,
+            name=name,
         ))
         calls[key] = (fn, kernel.nm)
         return calls[key]
@@ -854,12 +868,14 @@ def _build_tiled_kernel(graph: DataflowGraph, group: FusionGroup,
 
 
 def make_tiled_callable(graph: DataflowGraph, group: FusionGroup,
-                        dtype, *, interpret=None, tile_resolve=None):
+                        dtype, *, interpret=None, tile_resolve=None,
+                        name=None):
     """Returns fn(scalars: {(r,s): val}, vec_ins: {(r,p): array}) ->
     {(r,p): value} for a level-3 gemm-anchored group. vec_ins carries
     the three anchor matrices under (anchor, A/B/C) alongside the
     member panels and vectors. `tile_resolve` is a `TilePlan.lookup`
-    resolver overriding the (bm, bn, bk) tile per (m, n, k) bucket."""
+    resolver overriding the (bm, bn, bk) tile per (m, n, k) bucket;
+    `name` names the kernel (`group_key`)."""
     interpret = default_interpret() if interpret is None else interpret
     sig = _tiled_signature(graph, group)
     calls: Dict[tuple, Callable] = {}
@@ -903,6 +919,7 @@ def make_tiled_callable(graph: DataflowGraph, group: FusionGroup,
             out_shape=out_shapes,
             scratch_shapes=[] if kernel.single
             else [pltpu.VMEM((bm, bn), jnp.float32)],
+            name=name,
             interpret=interpret,
         ))
         calls[key] = fn
@@ -1004,7 +1021,8 @@ def emit_program(graph: DataflowGraph, groups: List[FusionGroup],
                 make = make_anchored_callable
             fused_callables[gi] = make(
                 graph, g, dtype, interpret=interpret,
-                tile_resolve=tiles.lookup(f"g{gi}") if tiles else None)
+                tile_resolve=tiles.lookup(f"g{gi}") if tiles else None,
+                name=group_key(graph.spec.name, gi))
 
     # call-time tile resolvers for standalone dispatches
     standalone_resolvers = {}
@@ -1057,7 +1075,8 @@ def emit_program(graph: DataflowGraph, groups: List[FusionGroup],
             return jnp.asarray(inputs[b.input_name], jnp.float32)
 
         for gi, g in enumerate(groups):
-            with _group_span(gi, g, timed):
+            with jax.named_scope(group_key(graph.spec.name, gi)), \
+                    _group_span(gi, g, timed):
                 if gi in fused_callables:
                     run = fused_callables[gi]
                     sig = run.signature

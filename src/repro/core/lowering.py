@@ -283,9 +283,6 @@ def lower(raw, *, mode: str = "dataflow", fuse: Optional[bool] = None,
             and fault.matches(ir.spec.name):
         from repro.guard import chaos as _chaos
         ir.fn = _chaos.wrap_program_fn(ir.fn, fault)
-        obs.event("guard.fault.armed", program=ir.spec.name,
-                  kind=fault.kind, output=fault.output,
-                  iteration=fault.iteration)
     return ir
 
 

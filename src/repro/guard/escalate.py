@@ -220,8 +220,6 @@ def solve_with_policy(A, b, x0=None, *, tol: float = 1e-6,
                   residual=att.residual,
                   duration_s=round(dur, 6), straggler=slow)
         obs.counter(f"guard.attempts.{att.status_name.lower()}")
-        if slow:
-            obs.counter("guard.stragglers")
         return att, code, res
 
     def finish(res):
@@ -268,7 +266,6 @@ def solve_with_policy(A, b, x0=None, *, tol: float = 1e-6,
         if code == ST.CONVERGED:
             return finish(res)
 
-    obs.counter("guard.recovery_failed")
     raise RecoveryError(
         f"all {len(attempts)} escalation attempts failed "
         f"(last: {attempts[-1].solver} -> {attempts[-1].status_name})"

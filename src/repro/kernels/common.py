@@ -74,14 +74,17 @@ def mxu_dot(a, b):
 
 
 def pad_to(x: jax.Array, multiple: int, axis: int = 0, value=0):
-    """Zero-pad `axis` of x up to the next multiple."""
+    """Zero-pad `axis` of x up to the next multiple. The pad runs under
+    the name scope `pad`, so its device ops carry `/pad/` in their
+    `op_name` and a profiler trace can attribute their time."""
     n = x.shape[axis]
     rem = (-n) % multiple
     if rem == 0:
         return x
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, rem)
-    return jnp.pad(x, widths, constant_values=value)
+    with jax.named_scope("pad"):
+        return jnp.pad(x, widths, constant_values=value)
 
 
 def as_2d(x: jax.Array, lanes: int = LANES):

@@ -14,7 +14,18 @@ Instrumented sites across the stack:
   `kernel.group` timing spans around concrete executions;
 * `solvers.driver` — `solver.solve` spans, `loop.trace` events (the
   compile-once counter) and `solver.result` convergence telemetry
-  (iterations, final residual, converged — never the NaN tail).
+  (iterations, final residual, converged — never the NaN tail);
+* `blas.executable` — the always-on `blas.run` aggregate (calls and
+  host seconds inside `Executable.run`), and with recording on a
+  `blas.run` profiler annotation per call;
+* the host runtime — `host.gc.gen<g>` aggregates and `host.gc`
+  annotations from the garbage collector's hook, and `jax.compile`
+  spans from JAX's compile-path durations (both while enabled).
+
+While recording is on, every span is also a profiler annotation, and
+the generated program's ops run under one name scope per fusion group
+(`<program>.g<i>`) with pads under `pad`, so a profiler trace
+attributes device time to both.
 
 Typical use:
 
@@ -27,18 +38,21 @@ or `REPRO_OBS_JSONL=trace.jsonl python my_script.py` with no code
 changes. `Executable.profile(shapes)` builds on the same records to
 produce a modeled-vs-measured `DriftReport` per fused group.
 """
-from .core import (NULL_SPAN, Registry, block, capture,  # noqa: F401
+from .core import (NULL_SPAN, Aggregate, Registry,  # noqa: F401
+                   aggregate, aggregates, annotate, block, capture,
                    concrete, counter, counters, disable, enable,
                    enabled, event, export, get_registry, null_span,
                    records, reset, span)
-from .report import (DriftReport, DriftRow, diff_summaries,  # noqa: F401
-                     format_summary, join_drift, load_jsonl,
-                     summarize_records)
+from .report import (DriftReport, DriftRow, compile_seconds,  # noqa: F401
+                     diff_summaries, format_summary, join_drift,
+                     load_jsonl, summarize_records)
 
 __all__ = [
-    "DriftReport", "DriftRow", "NULL_SPAN", "Registry", "block",
-    "capture", "concrete", "counter", "counters", "diff_summaries",
-    "disable", "enable", "enabled", "event", "export",
-    "format_summary", "get_registry", "join_drift", "load_jsonl",
-    "null_span", "records", "reset", "span", "summarize_records",
+    "Aggregate", "DriftReport", "DriftRow",
+    "NULL_SPAN", "Registry", "aggregate", "aggregates", "annotate",
+    "block", "capture", "compile_seconds", "concrete", "counter",
+    "counters", "diff_summaries", "disable", "enable", "enabled",
+    "event", "export", "format_summary", "get_registry", "join_drift",
+    "load_jsonl", "null_span", "records", "reset", "span",
+    "summarize_records",
 ]

@@ -12,7 +12,9 @@ Design constraints, in priority order:
    entrypoint starts with one attribute check against the process
    registry; `span()` returns a shared no-op object without touching
    the clock. Nothing is allocated, nothing is written, and the
-   instrumented code paths trace/jit exactly as before.
+   instrumented code paths trace/jit exactly as before. The one
+   exception is the always-on `blas.run` aggregate: two clock reads
+   a call.
 2. **Trace-safe when enabled.** Instrumented sites live inside code
    that JAX may be tracing; recording plain-python metadata during a
    trace is harmless, but *timing* a traced region measures trace
@@ -20,7 +22,21 @@ Design constraints, in priority order:
    concreteness (`concrete()`), so spans around generated kernels only
    time real executions.
 3. **Stdlib only.** The registry, the JSONL schema, and the CLI have
-   no dependency on jax — a JSONL file is readable anywhere.
+   no dependency on jax — a JSONL file is readable anywhere; jax is
+   imported lazily, for the profiler bridge and the compile listener.
+4. **On the profiler's clock.** While recording is on, every span
+   also enters a `jax.profiler.TraceAnnotation` of its name and
+   attributes, so under a profiler session it sits on the host line of
+   the thread that ran it, nested by its parent, on the same clock as
+   the device's ops.
+
+Sites that fire on every call keep an `Aggregate` (count, total and
+largest seconds) in place of one record per call; `blas.run` keeps one
+always, recording or not. `enable()` also installs a `gc.callbacks`
+hook (`host.gc.gen<g>` aggregates, and a `host.gc` annotation around
+each collection) and a `jax.monitoring` listener that turns JAX's
+trace, lowering and compile durations into `jax.compile` spans;
+`disable()` removes both.
 
 Record schema (one JSON object per line):
 
@@ -28,6 +44,8 @@ Record schema (one JSON object per line):
      "dur_s": ..., "attrs": {...}}
     {"kind": "counter", "name": ..., "n": 1, "attrs": {...}}
     {"kind": "event",   "name": ..., "t": t_s, "attrs": {...}}
+    {"kind": "aggregate", "name": ..., "count": n, "total_s": ...,
+     "max_s": ...}     (module-level `export()` only, one per aggregate)
 
 Timestamps are seconds relative to the registry's creation
 (perf_counter based — ordering and duration, not wall-clock dates).
@@ -36,12 +54,14 @@ from __future__ import annotations
 
 import atexit
 import contextlib
+import gc
 import json
 import os
 import pathlib
+import sys
 import threading
 import time
-from typing import Iterable, List, Mapping, Optional
+from typing import Dict, Iterable, List, Mapping, Optional
 
 
 class Registry:
@@ -72,11 +92,14 @@ class Registry:
             self.counters.clear()
             self._stack.clear()
 
-    def export_jsonl(self, path) -> pathlib.Path:
-        """Write every record as one JSON line; returns the path."""
+    def export_jsonl(self, path, extra: Iterable[dict] = ()
+                     ) -> pathlib.Path:
+        """Write every record, then `extra`, as one JSON line each;
+        returns the path."""
         path = pathlib.Path(path)
         with self._lock:
-            lines = [json.dumps(r, default=repr) for r in self.records]
+            lines = [json.dumps(r, default=repr)
+                     for r in [*self.records, *extra]]
         path.write_text("\n".join(lines) + ("\n" if lines else ""))
         return path
 
@@ -94,33 +117,46 @@ def enabled() -> bool:
 
 
 def enable(jsonl: Optional[str] = None) -> Registry:
-    """Turn recording on. `jsonl` remembers a default export path for
+    """Turn recording on, with the garbage collector's hook and the
+    compile listener. `jsonl` remembers a default export path for
     `export()` (and the atexit flush when activated via the
     REPRO_OBS_JSONL environment variable)."""
     global _EXPORT_PATH
     _REGISTRY.enabled = True
     if jsonl is not None:
         _EXPORT_PATH = str(jsonl)
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+    _compile_listener(install=True)
     return _REGISTRY
 
 
 def disable() -> None:
     _REGISTRY.enabled = False
+    if _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+    _compile_listener(install=False)
 
 
 def reset() -> None:
-    """Drop all accumulated records and counters (keeps enabled state)."""
+    """Drop all accumulated records and counters and zero every
+    aggregate (keeps enabled state)."""
     _REGISTRY.clear()
+    for a in list(_AGGREGATES.values()):
+        a.count, a.total_s, a.max_s = 0, 0.0, 0.0
 
 
 def export(path: Optional[str] = None) -> pathlib.Path:
     """Export accumulated records as JSONL to `path` (or the path given
-    to `enable()`)."""
+    to `enable()`), then one summary record per aggregate that has
+    counted anything."""
     target = path if path is not None else _EXPORT_PATH
     if target is None:
         raise ValueError(
             "no export path: pass one to export() or enable(jsonl=...)")
-    return _REGISTRY.export_jsonl(target)
+    summary = [{"kind": "aggregate", "name": name, **snap}
+               for name, snap in aggregates().items() if snap["count"]]
+    return _REGISTRY.export_jsonl(target, extra=summary)
 
 
 @contextlib.contextmanager
@@ -160,23 +196,39 @@ def null_span() -> _NullSpan:
     return NULL_SPAN
 
 
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation` once jax has been imported, else
+    None (no profiler can run then). Never imports jax itself: the
+    registry needs none, and a collection can start while jax is half
+    imported."""
+    return getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                   None)
+
+
 class _Span:
-    __slots__ = ("_reg", "name", "attrs", "_t0", "_path")
+    __slots__ = ("_reg", "name", "attrs", "_t0", "_path", "_ann")
 
     def __init__(self, reg: Registry, name: str, attrs: dict):
         self._reg = reg
         self.name = name
         self.attrs = attrs
+        self._ann = None
 
     def __enter__(self):
         reg = self._reg
         reg._stack.append(self.name)
         self._path = "/".join(reg._stack)
+        ann = _trace_annotation()
+        if ann is not None:
+            self._ann = ann(self.name, **self.attrs)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         reg = self._reg
         if reg._stack and reg._stack[-1] == self.name:
             reg._stack.pop()
@@ -187,11 +239,131 @@ class _Span:
 
 
 def span(name: str, **attrs):
-    """Context manager timing one region. Disabled -> shared no-op."""
+    """Context manager timing one region, and while recording is on a
+    profiler annotation of the same name and attributes. Disabled ->
+    shared no-op."""
     reg = _REGISTRY
     if not reg.enabled:
         return NULL_SPAN
     return _Span(reg, name, attrs)
+
+
+def annotate(name: str, **attrs):
+    """A profiler annotation alone, with no record: for sites that fire
+    on every call and keep an `Aggregate` instead. Disabled (or no
+    jax) -> shared no-op."""
+    ann = _trace_annotation() if _REGISTRY.enabled else None
+    if ann is None:
+        return NULL_SPAN
+    return ann(name, **attrs)
+
+
+class Aggregate:
+    """Count, total and largest seconds of one site that fires on every
+    call. Adding costs no allocation and appends no record, so a long
+    run grows nothing for the garbage collector to walk. Not locked:
+    sites that several threads share may lose a rare count."""
+    __slots__ = ("name", "count", "total_s", "max_s")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.count, self.total_s, self.max_s = 0, 0.0, 0.0
+
+    def add(self, seconds: float) -> None:
+        self.count += 1
+        self.total_s += seconds
+        if seconds > self.max_s:
+            self.max_s = seconds
+
+    def snapshot(self) -> dict:
+        return {"count": self.count, "total_s": self.total_s,
+                "max_s": self.max_s}
+
+
+_AGGREGATES: Dict[str, Aggregate] = {}
+
+
+def aggregate(name: str) -> Aggregate:
+    """The process's aggregate `name`, made on first use. Aggregates
+    count whether or not recording is on; `reset()` zeroes them."""
+    agg = _AGGREGATES.get(name)
+    if agg is None:
+        agg = _AGGREGATES.setdefault(name, Aggregate(name))
+    return agg
+
+
+def aggregates() -> Dict[str, dict]:
+    """{name: {"count", "total_s", "max_s"}} of every aggregate. The
+    difference of two snapshots is what happened between them."""
+    return {name: a.snapshot() for name, a in list(_AGGREGATES.items())}
+
+
+class _GcHook:
+    """`gc.callbacks` entry installed by `enable()`: each collection
+    adds its pause to the `host.gc.gen<generation>` aggregate, and
+    while the profiler runs it sits inside a `host.gc` annotation."""
+
+    def __init__(self):
+        self._t0 = 0.0
+        self._ann = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = _trace_annotation()
+            if ann is not None:
+                self._ann = ann("host.gc", generation=info["generation"])
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        aggregate(f"host.gc.gen{info['generation']}").add(dt)
+
+
+_gc_hook = _GcHook()
+
+# JAX's compile-path durations, by the `stage` a `jax.compile` span
+# gets. `compile` also covers a load from the persistent cache.
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+def _on_compile(event: str, start: float, end: float, **kw) -> None:
+    """`jax.monitoring` time-span listener: one `jax.compile` span per
+    compile-path event, moved from the wall clock JAX reports onto the
+    registry's."""
+    stage = _COMPILE_EVENTS.get(event)
+    reg = _REGISTRY
+    if stage is None or not reg.enabled:
+        return
+    shift = time.perf_counter() - time.time()
+    reg.add({"kind": "span", "name": "jax.compile",
+             "path": "/".join([*reg._stack, "jax.compile"]),
+             "t": start + shift - reg._epoch, "dur_s": end - start,
+             "attrs": {"stage": stage, "fun": kw.get("fun_name")}})
+
+
+_listening = False
+
+
+def _compile_listener(install: bool) -> None:
+    global _listening
+    if install == _listening:
+        return
+    try:
+        from jax import monitoring
+    except ImportError:
+        return
+    if install:
+        monitoring.register_event_time_span_listener(_on_compile)
+    else:
+        monitoring.unregister_event_time_span_listener(_on_compile)
+    _listening = install
 
 
 def counter(name: str, n: int = 1, **attrs) -> None:
@@ -254,5 +426,5 @@ def block(values: Iterable) -> None:
 _env_path = os.environ.get("REPRO_OBS_JSONL")
 if _env_path:
     enable(jsonl=_env_path)
-    atexit.register(lambda: _REGISTRY.export_jsonl(_env_path))
+    atexit.register(lambda: export(_env_path))
 del _env_path

@@ -31,13 +31,15 @@ def load_jsonl(path) -> List[dict]:
 def summarize_records(records: Iterable[dict]) -> dict:
     """Aggregate a record stream:
 
-    spans    -> name: {count, total_s, mean_s, max_s}
-    counters -> name: total n
-    events   -> name: count
+    spans      -> name: {count, total_s, mean_s, max_s}
+    counters   -> name: total n
+    events     -> name: count
+    aggregates -> name: {count, total_s, mean_s, max_s}
     """
     spans: dict = {}
     counters: dict = {}
     events: dict = {}
+    aggs: dict = {}
     for r in records:
         kind = r.get("kind")
         name = r.get("name", "?")
@@ -51,20 +53,46 @@ def summarize_records(records: Iterable[dict]) -> dict:
             counters[name] = counters.get(name, 0) + int(r.get("n", 1))
         elif kind == "event":
             events[name] = events.get(name, 0) + 1
-    for s in spans.values():
+        elif kind == "aggregate" and r.get("count"):
+            aggs[name] = {k: r[k] for k in ("count", "total_s", "max_s")}
+    for s in [*spans.values(), *aggs.values()]:
         s["mean_s"] = s["total_s"] / s["count"]
-    return {"spans": spans, "counters": counters, "events": events}
+    return {"spans": spans, "counters": counters, "events": events,
+            "aggregates": aggs}
+
+
+def compile_seconds(records: Iterable[dict], t0: Optional[float] = None,
+                    t1: Optional[float] = None) -> float:
+    """Seconds spent compiling: the union of the `jax.compile` and
+    `lowering.*` spans, clipped to [t0, t1] (registry seconds), so that
+    nested traces and a lowering that runs inside a trace count once."""
+    lo = float("-inf") if t0 is None else t0
+    hi = float("inf") if t1 is None else t1
+    spans = sorted(
+        (max(float(r["t"]), lo), min(float(r["t"]) + float(r["dur_s"]), hi))
+        for r in records if r.get("kind") == "span"
+        and (r.get("name") == "jax.compile"
+             or str(r.get("name", "")).startswith("lowering.")))
+    total, end = 0.0, float("-inf")
+    for s, e in spans:
+        if e <= max(s, end):
+            continue
+        total += e - max(s, end)
+        end = e
+    return total
 
 
 def format_summary(summary: Mapping) -> str:
     lines = []
-    if summary["spans"]:
-        lines.append("spans:")
+    for kind in ("spans", "aggregates"):
+        timed = summary.get(kind)
+        if not timed:
+            continue
+        lines.append(f"{kind}:")
         lines.append(f"  {'name':<32} {'count':>7} {'total_ms':>10} "
                      f"{'mean_ms':>10} {'max_ms':>10}")
-        for name in sorted(summary["spans"],
-                           key=lambda n: -summary["spans"][n]["total_s"]):
-            s = summary["spans"][name]
+        for name in sorted(timed, key=lambda n: -timed[n]["total_s"]):
+            s = timed[name]
             lines.append(
                 f"  {name:<32} {s['count']:>7} "
                 f"{1e3 * s['total_s']:>10.3f} "

@@ -465,19 +465,9 @@ class LoopProgram(SolverProgram):
     def _run_inner(self, cs, env):
         """One nested iterate: its own `lax.while_loop` inside the
         enclosing loop's body trace. Inner state initializes from the
-        enclosing environment; yields export final inner state. When
-        the enclosing environment is concrete (eager profiling) the
-        whole inner loop is timed as one `loop.inner` span — its body
-        runs under lax control flow, so per-kernel spans inside it
-        deliberately stay silent."""
-        ispec = cs.stage
-        timed = obs.enabled() and obs.concrete(env.values())
-        with (obs.span("loop.inner", program=self.name,
-                       counter=ispec.counter) if timed
-              else obs.NULL_SPAN):
-            self._run_inner_body(cs, env)
-
-    def _run_inner_body(self, cs, env):
+        enclosing environment; yields export final inner state. Its
+        body runs under lax control flow, where per-kernel spans stay
+        silent."""
         ispec = cs.stage
         state = self._init_fields(ispec.state, env)
         stop = ispec.stop

@@ -269,9 +269,6 @@ def tune_program(raw, shapes: Mapping, *, mode: str = "dataflow",
     inputs = _synthesize(ir0, shapes)
     sites = _discover_sites(ir0, shapes)
     baseline_us = _time_ir(ir0, inputs, iters)
-    obs.event("tune.start", program=ir0.spec.name, digest=digest[:12],
-              mode=mode, device=dk, sites=len(sites),
-              baseline_us=baseline_us)
 
     plan_sites: Dict[str, Dict[str, C.TileConfig]] = {}
     winners: Dict[str, C.TileConfig] = {}
@@ -306,10 +303,7 @@ def tune_program(raw, shapes: Mapping, *, mode: str = "dataflow",
             winners[info.site] = best_cfg
             site_best[info.site] = best_us
             current_us = best_us
-        if sweeps >= budget and info is not sites[-1]:
-            obs.event("tune.budget_exhausted", budget=budget,
-                      remaining_sites=[
-                          s.site for s in sites[sites.index(info) + 1:]])
+        if sweeps >= budget:
             break
 
     final_plan = C.TilePlan.from_dict(plan_sites)
@@ -327,10 +321,6 @@ def tune_program(raw, shapes: Mapping, *, mode: str = "dataflow",
         store.put_artifact(digest, mode, fuse, anchor, dk, spec=raw,
                            plan=final_plan, tuned=True)
 
-    obs.event("tune.done", program=ir0.spec.name, digest=digest[:12],
-              sweeps=sweeps, baseline_us=baseline_us,
-              tuned_us=tuned_us, winners={s: c.key()
-                                          for s, c in winners.items()})
     return TuneReport(
         program=ir0.spec.name, digest=digest, mode=mode, fuse=fuse,
         anchor=anchor, device_kind=dk, baseline_us=baseline_us,

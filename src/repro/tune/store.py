@@ -160,7 +160,6 @@ class TuningTable:
                       quarantine=(str(quarantine)
                                   if quarantine else None),
                       error=str(e))
-            obs.counter("tune.store.corrupt")
             return _empty_doc()
         if not isinstance(doc, Mapping) or \
                 doc.get("version") != SCHEMA_VERSION:

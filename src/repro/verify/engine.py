@@ -76,15 +76,7 @@ def analyze(raw, *, mode: str = "dataflow") -> Report:
                     g.order = None   # cycle: leave order unset
                 passes.run_dataflow_passes(spec, g, sink, mode=mode)
 
-    report = sink.report(program=name, kind=kind)
-    if obs.enabled():
-        for d in report.diagnostics:
-            obs.counter(f"verify.{d.severity}", code=d.code)
-        obs.event("verify.done", program=name, kind=kind,
-                  errors=len(report.errors),
-                  warnings=len(report.warnings),
-                  infos=len(report.infos))
-    return report
+    return sink.report(program=name, kind=kind)
 
 
 def check(raw, *, mode: str = "dataflow") -> Report:

@@ -296,3 +296,208 @@ def test_profile_rejects_bad_iters_and_class_solvers():
     wrapped = blas.Executable.from_solver(BiCGStab())
     with pytest.raises(TypeError):
         wrapped.profile({"A": (8, 8), "b": 8})
+
+
+# ---------------------------------------------------------------------------
+# The profiler bridge, aggregates, the GC hook, compile spans and the
+# group / pad name scopes
+# ---------------------------------------------------------------------------
+
+
+def _profile(tmp_path, body):
+    """Run `body` under a profiler session; the session's ProfileData."""
+    import glob
+
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    return ProfileData.from_file(path)
+
+
+def _host_events(pd, names):
+    """{line name: [(name, start_ns, end_ns, stats)]} of host events
+    named in `names`."""
+    import warnings
+
+    out = {}
+    for plane in pd.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            with warnings.catch_warnings():
+                # the profiler's stats type lacks __module__
+                warnings.simplefilter("ignore", DeprecationWarning)
+                evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats)) for e in line.events
+                       if e.name in names]
+            if evs:
+                out[line.name] = evs
+    return out
+
+
+def test_spans_nest_on_the_host_line_of_a_profiler_trace(tmp_path):
+    import time
+
+    def body():
+        with obs.span("obs_probe.outer", call=3):
+            with obs.span("obs_probe.inner"):
+                time.sleep(0.005)
+            time.sleep(0.002)
+
+    with obs.capture() as reg:
+        pd = _profile(tmp_path, body)
+        recs = {r["name"]: r for r in reg.records}
+    lines = _host_events(pd, {"obs_probe.outer", "obs_probe.inner"})
+    assert len(lines) == 1                  # one thread ran both
+    (evs,) = lines.values()
+    ev = {name: (s, e, st) for name, s, e, st in evs}
+    (os_, oe, ostats), (is_, ie, _) = (ev["obs_probe.outer"],
+                                       ev["obs_probe.inner"])
+    assert os_ <= is_ and ie <= oe          # nested by its parent
+    assert ostats["call"] == 3              # attributes are metadata
+    assert recs["obs_probe.inner"]["path"] == "obs_probe.outer/obs_probe.inner"
+    for name, (s, e, _) in ev.items():
+        dur = recs[name]["dur_s"]
+        assert abs((e - s) * 1e-9 - dur) <= max(50e-6, 0.05 * dur)
+
+
+def test_blas_run_aggregate_counts_calls_and_appends_no_record():
+    import repro.core as core
+    exe = blas.compile(core.AXPYDOT_SPEC)
+    n = 64
+    ops = {"neg_alpha": -0.5, "v": jnp.ones(n), "w": jnp.ones(n),
+           "u": jnp.ones(n)}
+    exe.run(**ops)                          # compiles
+    before = obs.aggregates()["blas.run"]
+    with obs.capture() as reg:
+        for _ in range(4):
+            exe.run(**ops)
+        assert reg.records == []            # no record per call
+    for _ in range(3):
+        exe.run(**ops)                      # counted with recording off
+    after = obs.aggregates()["blas.run"]
+    assert after["count"] - before["count"] == 7
+    assert after["total_s"] > before["total_s"]
+    assert after["max_s"] >= before["max_s"]
+    assert obs.records() == []
+
+
+def test_gc_hook_counts_pauses_and_annotates_them(tmp_path):
+    import gc
+
+    obs.enable()
+    try:
+        hook = obs.core._gc_hook
+        assert gc.callbacks.count(hook) == 1
+        before = obs.aggregates().get("host.gc.gen2", {"count": 0})
+        gc.collect()
+        assert obs.aggregates()["host.gc.gen2"]["count"] \
+            == before["count"] + 1
+        pd = _profile(tmp_path, gc.collect)
+        (evs,) = _host_events(pd, {"host.gc"}).values()
+        assert evs[0][3]["generation"] == 2
+    finally:
+        obs.disable()
+        obs.reset()
+    assert hook not in gc.callbacks
+
+
+def test_compile_spans_and_their_union():
+    obs.enable()
+    try:
+        jax.jit(lambda x: jnp.sin(x) * 3.0 + 0.25)(jnp.ones(7))
+        spans = [r for r in obs.records() if r["name"] == "jax.compile"]
+    finally:
+        obs.disable()
+        obs.reset()
+    assert {s["attrs"]["stage"] for s in spans} == {"trace", "lower",
+                                                    "compile"}
+    total = obs.compile_seconds(spans)
+    assert 0 < total <= sum(s["dur_s"] for s in spans) + 1e-9
+    # disabled: the listener is gone, so a new compile records nothing
+    jax.jit(lambda x: jnp.cos(x) - 0.5)(jnp.ones(7))
+    assert obs.records() == []
+
+
+def test_compile_seconds_counts_nested_and_overlapping_spans_once():
+    def sp(name, t, dur):
+        return {"kind": "span", "name": name, "t": t, "dur_s": dur}
+
+    recs = [sp("jax.compile", 0.0, 1.0),       # a trace ...
+            sp("jax.compile", 0.2, 0.3),       # ... with one nested
+            sp("lowering.emit", 0.9, 0.6),     # overlaps its end
+            sp("solver.solve", 0.0, 9.0),      # not a compile
+            sp("jax.compile", 3.0, 0.5)]
+    assert obs.compile_seconds(recs) == pytest.approx(2.0)
+    assert obs.compile_seconds(recs, t0=0.5, t1=3.25) == pytest.approx(
+        1.25)
+
+
+def test_export_writes_one_summary_record_per_aggregate(tmp_path):
+    obs.aggregate("obs_probe.agg").add(0.25)
+    obs.aggregate("obs_probe.agg").add(0.5)
+    path = obs.export(str(tmp_path / "agg.jsonl"))
+    recs = [r for r in obs.load_jsonl(path) if r["kind"] == "aggregate"]
+    mine = [r for r in recs if r["name"] == "obs_probe.agg"]
+    assert mine == [{"kind": "aggregate", "name": "obs_probe.agg",
+                     "count": 2, "total_s": 0.75, "max_s": 0.5}]
+    s = obs.summarize_records(recs)
+    assert s["aggregates"]["obs_probe.agg"]["mean_s"] == 0.375
+    assert "aggregates:" in obs.format_summary(s)
+
+
+def test_mvt_ops_carry_their_group_and_pad_scopes():
+    import re
+
+    b = blas.program("obs_probe_mvt")
+    b.gemv(alpha=1.0, beta=1.0, A="A", x="y1", y="x1", out="x1_out")
+    b.gemvt(alpha=1.0, beta=1.0, A="A", x="y2", y="x2", out="x2_out")
+    exe = blas.compile(b, tiles="default")
+    n = 300                                 # not a multiple of a block
+    args = {"A": jnp.ones((n, n)),
+            **{k: jnp.ones(n) for k in ("y1", "y2", "x1", "x2")}}
+    text = jax.jit(exe._impl.ir.fn).lower(args).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    groups = {g for p in paths for g in re.findall(r"/(obs_probe_mvt\.g\d+)/",
+                                                   p)}
+    assert groups == {"obs_probe_mvt.g0", "obs_probe_mvt.g1"}
+    assert any("/pad/" in p for p in paths)
+
+
+def test_spans_annotate_when_recording_began_before_jax_was_imported(
+        tmp_path):
+    """`enable()` before `import jax`: collections that start while jax
+    is half imported must not leave the profiler bridge off for good."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    code = f"""
+import gc, glob
+from repro import obs
+obs.enable()
+gc.set_threshold(50)             # collect often while jax imports
+import jax
+gc.set_threshold(700)
+from jax.profiler import ProfileData
+jax.profiler.start_trace({str(tmp_path)!r})
+with obs.span("obs_probe.early"):
+    pass
+jax.profiler.stop_trace()
+(path,) = glob.glob({str(tmp_path / "**" / "*.xplane.pb")!r}, recursive=True)
+names = {{e.name for p in ProfileData.from_file(path).planes
+         for line in p.lines for e in line.events}}
+print("obs_probe.early" in names)
+"""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": str(pathlib.Path(obs.__file__).parents[2])}
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.split()[-1] == "True"
