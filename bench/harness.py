@@ -159,6 +159,10 @@ def _window(kind, seconds: float, counter: _CompileCounter, log,
     return win
 
 
+def _since(now: Optional[int], before: Optional[int]) -> Optional[int]:
+    return None if before is None else now - before
+
+
 def measure(kind, seconds: float, trace: bool, setup_s: float,
             log=print) -> Measured:
     """Run the window, then with `trace` a second window under the
@@ -168,19 +172,28 @@ def measure(kind, seconds: float, trace: bool, setup_s: float,
     comes from the first window, which runs with the profiler off: its
     host tracer records every annotation and dispatch, and so adds time
     to the very calls it watches. Only the device's numbers come from
-    the traced window."""
+    the traced window.
+
+    `rec["iterations"]` and `rec["traced_iterations"]` are the loop
+    iterations that the solves of the measured and of the traced
+    window ran (the kind's `iterations`, read around each window),
+    and None for a kind that counts none, as `calls`."""
     counter = _compile_counter()
+    before = kind.iterations
     win = _window(kind, seconds, counter, log, "measured")
-    traced = None
+    iterations = _since(kind.iterations, before)
+    traced = traced_iterations = None
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         opts = profiler.ProfileOptions()
         opts.python_tracer_level = 0
+        before = kind.iterations
         profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
         try:
             traced = _window(kind, seconds, counter, log, "traced")
         finally:
             profiler.stop_trace()
+        traced_iterations = _since(kind.iterations, before)
     devices = jax.devices()
     stats = [d.memory_stats() or {} for d in devices]
     device = {"platform": devices[0].platform,
@@ -203,6 +216,8 @@ def measure(kind, seconds: float, trace: bool, setup_s: float,
            "window_s": win.seconds, "setup_s": setup_s,
            "dispatch_s": win.dispatch_seconds,
            "traced_requests": traced.requests if traced else None,
+           "iterations": iterations,
+           "traced_iterations": traced_iterations,
            "work_bytes": nbytes, "work_flops": flops,
            "peaks": peaks.peaks(device["kind"])
            if device["platform"] == "tpu" else None,

@@ -18,6 +18,25 @@ function. Nothing here knows a configuration by name.
   `work(cfg, name)`, `reference_program(cfg, name, precision)` and
   `LIMITS`.
 
+`solves`: one request is one solve, one `Executable.run` of the loop
+  program `program`. The configuration's `program(cfg, name)` returns
+  a loop spec (or a builder loop) with its `rtol` and `max_iters`
+  baked in, compiled once as for `calls`. `fixed` holds the resident
+  operands (the matrix and any preconditioner data), the pool each
+  request's right-hand-side panel and `x0`. The traffic parameters
+  are those of `calls`, and `status`: the `repro.guard.status` name
+  that every checked solve must end in (`"CONVERGED"` by default).
+  A solve is in flight until its `x`, `iterations` and `status` are
+  ready; `keep` adds each completed solve's `iterations` to the
+  kind's `iterations`, which the harness reads around each window.
+  `reference(cfg, name, seed, pool_indices)` gives `{"x": (k, n, s)
+  float64}`, and the check reports `err`, the worst column's
+  ‖x_j − x*_j‖ / ‖x*_j‖ over the sampled solves, and `unconverged`,
+  how many of them ended in another status than `status`. The
+  control (`reference_program`) returns a mapping with `x`,
+  `iterations` and `status` (a `repro.guard.status` code), read
+  through the same accessor as the library's `SolverResult`.
+
 `precision`, where given, puts the configuration's plain reference in
 the library's place, computed at that precision: `"highest"` is the
 configuration's own (float32), `"high"` the three-pass bfloat16
@@ -28,6 +47,7 @@ from __future__ import annotations
 
 import random
 import sys
+from collections.abc import Mapping
 from typing import Optional
 
 import jax
@@ -45,8 +65,15 @@ def _reading(v: float) -> float:
     return float(v) if np.isfinite(v) else sys.float_info.max
 
 
+def _field(out, name: str):
+    """A solve's `name`: an attribute of the library's `SolverResult`,
+    a key of the mapping that the control returns."""
+    return out[name] if isinstance(out, Mapping) else getattr(out, name)
+
+
 class Calls:
     unit = "call"
+    iterations = None        # a solve's kind counts its iterations
 
     def __init__(self, cfg, module, traffic, seed: int,
                  precision: Optional[str] = None):
@@ -112,4 +139,55 @@ class Calls:
         return {"err": err}, failed
 
 
-KINDS = {"calls": Calls}
+class Solves(Calls):
+    """One request is one solve of a loop program; one solve is one
+    call of it, so the unit stays `call`."""
+
+    def __init__(self, cfg, module, traffic, seed: int,
+                 precision: Optional[str] = None):
+        from repro.guard.status import STATUS_NAMES
+        codes = {name: code for code, name in STATUS_NAMES.items()}
+        name = traffic.get("status", "CONVERGED")
+        if name not in codes:
+            raise ValueError(f"status {name!r} is not one of {sorted(codes)}")
+        self.status = codes[name]
+        super().__init__(cfg, module, traffic, seed, precision)
+        self.iterations = 0
+
+    @staticmethod
+    def _arrays(out) -> list:
+        return [_field(out, k) for k in ("x", "iterations", "status")]
+
+    @staticmethod
+    def wait(out) -> None:
+        jax.block_until_ready(Solves._arrays(out))
+
+    @staticmethod
+    def ready(out) -> bool:
+        return all(v.is_ready() for v in Solves._arrays(out))
+
+    def keep(self, i: int, out) -> None:
+        # a completed solve's count is one scalar, already on the host's
+        # side of the wait
+        self.iterations += int(_field(out, "iterations"))
+        super().keep(i, out)
+
+    def check(self) -> tuple:
+        """({"err": worst column's ‖x_j − x*_j‖ / ‖x*_j‖, "unconverged":
+        solves not ended in `status`}, failed solves), over the sampled
+        solves."""
+        want = self.module.reference(self.cfg, self.program, self.seed,
+                                     [k for _, k, _ in self.sample])["x"]
+        err, unconverged, failed = 0.0, 0, 0
+        for ref, (_, _, out) in zip(want, self.sample):
+            x = _f64(_field(out, "x")).reshape(ref.shape)
+            e = _reading(np.max(np.linalg.norm(x - ref, axis=0)
+                                / np.linalg.norm(ref, axis=0)))
+            off = int(_field(out, "status")) != self.status
+            failed += int(e > self.limits["err"] or off)
+            unconverged += int(off)
+            err = max(err, e)
+        return {"err": err, "unconverged": unconverged}, failed
+
+
+KINDS = {"calls": Calls, "solves": Solves}

@@ -1,4 +1,5 @@
-"""Matrix products of the plain references, at a stated precision.
+"""Matrix products of the plain references, at a stated precision,
+and the plain conjugate gradient that a solve's control runs on them.
 
 `"highest"` is a float32 product at full float32 precision. `"high"`
 is the three-pass bfloat16 product below it: each operand is split
@@ -43,3 +44,44 @@ def matmul(a, b, precision: str):
                          f"{PRECISIONS}")
     (ah, al), (bh, bl) = _split(a), _split(b)
     return mm(ah, bh) + (mm(ah, bl) + mm(al, bh))
+
+
+# the codes of `repro.guard.status` that a plain solve can end in
+CONVERGED, MAX_ITERS = 0, 1
+
+
+def cg(a, b, x0, rtol: float, max_iters: int, precision: str) -> dict:
+    """Conjugate gradient on each column of `b` ((n,) or (n, s)) in
+    plain jax.numpy, every product with `a` taken by `matmul` at
+    `precision`. The stop rule is the library's loop specs': the
+    worst column's residual norm at most `rtol` times the largest
+    column norm of `b`, or `max_iters` iterations. Gives `x` (shaped
+    as `b`), `iterations` and `status` (CONVERGED or MAX_ITERS), as
+    device arrays."""
+    import jax
+    import jax.numpy as jnp
+
+    shape = b.shape
+    b, x0 = b.reshape(shape[0], -1), x0.reshape(shape[0], -1)
+    r = b - matmul(a, x0, precision)
+    rz = jnp.sum(r * r, axis=0)
+    threshold = rtol * jnp.sqrt(jnp.max(jnp.sum(b * b, axis=0)))
+
+    def running(carry):
+        k, _, _, _, rz = carry
+        return jnp.logical_and(k < max_iters,
+                               jnp.sqrt(jnp.max(rz)) > threshold)
+
+    def step(carry):
+        k, x, r, p, rz = carry
+        q = matmul(a, p, precision)
+        alpha = rz / jnp.sum(p * q, axis=0)
+        x, r = x + p * alpha, r - q * alpha
+        rz_next = jnp.sum(r * r, axis=0)
+        return k + 1, x, r, r + p * (rz_next / rz), rz_next
+
+    k, x, _, _, rz = jax.lax.while_loop(
+        running, step, (jnp.int32(0), x0, r, r, rz))
+    status = jnp.where(jnp.sqrt(jnp.max(rz)) <= threshold,
+                       jnp.int8(CONVERGED), jnp.int8(MAX_ITERS))
+    return {"x": x.reshape(shape), "iterations": k, "status": status}

@@ -1,12 +1,15 @@
 """Arithmetic shared by the metric readers in `bench/metrics/`.
 
 A reader gets the run's record (`bench.harness.measure`): `unit`
-("call"), `requests`, `window_s`, `setup_s` and `dispatch_s` of the
-measured window (profiler off), `traced_requests` of the traced window
-(None without `--trace 1`), `work_bytes` and `work_flops` (the least
-one request needs, from the configuration's work function), `peaks`
-(`bench.peaks`, None off a TPU) and `trace` (`bench.trace.Reduced` of
-the traced window, else None). Each function returns None where the
+("call", for calls and solves alike), `requests`, `window_s`,
+`setup_s` and `dispatch_s` of the measured window (profiler off),
+`traced_requests` of the traced window (None without `--trace 1`),
+`iterations` and `traced_iterations` (the loop iterations that the
+solves of each window ran; None for calls, and the traced one without
+`--trace 1`), `work_bytes` and `work_flops` (the least one request
+needs, from the configuration's work function), `peaks` (`bench.peaks`,
+None off a TPU) and `trace` (`bench.trace.Reduced` of the traced
+window, else None). Each function returns None where the
 record has nothing to read, and the harness then leaves the metric
 out.
 """
