@@ -24,6 +24,9 @@ from bench import gen, numerics
 # PERF.md gives the readings it was set from.
 LIMITS = {"err": 1.5e-6}
 
+# sizes a test run on the CPU can hold, merged over the configuration
+SMALL = {"mvt": {"N": 300}, "gesummv": {"N": 200, "alpha": 1.5, "beta": 1.2}}
+
 # one hash stream per operand
 MATRICES = {"mvt": {"A": 1}, "gesummv": {"A": 2, "B": 3}}
 VECTORS = {"mvt": {"y1": 11, "y2": 12, "x1": 13, "x2": 14},

@@ -11,7 +11,18 @@ Everything a cell is made of is found from `BENCHMARK.json` by name:
 
 so a later cell, mix or metric is new files and a new entry, never an
 edit here. `bench.kinds` holds the request kinds, `bench.loop` the
-timing loop, `bench.trace` the trace reduction.
+timing loop, `bench.trace` the trace reduction, `bench.scopes` the
+device time per name scope.
+
+A new configuration's module gives what its request kind names
+(`bench.kinds`): `program`, `inputs`, `reference`, `work`,
+`reference_program`, `LIMITS`, and `SMALL`, the sizes at which the
+tests run its cells on the CPU. It may give `iteration_work` (a
+`solves` cell's least work per loop iteration) and `scope_work` (the
+least work under each fusion-group scope); `measure` puts what they
+give in `rec`, and `bench/reading.py` turns it into roofline shares.
+The tests take a new cell with no edit: each of them runs on every
+cell of `BENCHMARK.json` at its configuration's `SMALL`.
 """
 from __future__ import annotations
 
@@ -30,7 +41,7 @@ import jax
 import jax.monitoring
 from jax import profiler
 
-from bench import kinds, loop, peaks
+from bench import kinds, loop, peaks, scopes
 from bench import trace as tr
 
 BENCH = pathlib.Path(__file__).resolve().parent
@@ -55,7 +66,7 @@ def load_module(path: pathlib.Path):
     """Import a file of the benchmark by its path; its name may hold
     characters (`-`, `.`) that an import statement cannot."""
     name = "bench._loaded." + "".join(
-        c if c.isalnum() else "_" for c in str(path.relative_to(BENCH)))
+        c if c.isalnum() else "_" for c in str(path.resolve()))
     if name in sys.modules:
         return sys.modules[name]
     spec = importlib.util.spec_from_file_location(name, path)
@@ -81,6 +92,7 @@ class Cell:
     traffic: dict            # bench/traffic/<traffic>.json
     end_to_end: list         # the entries of BENCHMARK.json it reports
     per_layer: list
+    root: pathlib.Path = ROOT    # the checkout its files are found in
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -90,7 +102,8 @@ def _reports(metric: dict, cell: str) -> bool:
 def cell(name: str, entry: Optional[dict] = None,
          root: pathlib.Path = ROOT) -> Cell:
     """The cell `name` of `BENCHMARK.json`, or the one that `entry`
-    (a workload entry of the same form) describes."""
+    (a workload entry of the same form) describes, with every file
+    found under `root`."""
     bm = load_json(root / "BENCHMARK.json")
     if entry is None:
         entry = next((w for w in bm["workloads"] if w["name"] == name),
@@ -104,9 +117,11 @@ def cell(name: str, entry: Optional[dict] = None,
         name=name, chips=int(entry["chips"]),
         config=load_json(cfg_path),
         module=load_module(cfg_path.with_suffix(".py")),
-        traffic=load_json(BENCH / "traffic" / f"{entry['traffic']}.json"),
+        traffic=load_json(root / "bench" / "traffic"
+                          / f"{entry['traffic']}.json"),
         end_to_end=[m for m in bm["end_to_end"] if _reports(m, name)],
-        per_layer=[m for m in bm["per_layer"] if _reports(m, name)])
+        per_layer=[m for m in bm["per_layer"] if _reports(m, name)],
+        root=root)
 
 
 def prepare(c: Cell, seed: int, precision: Optional[str] = None):
@@ -177,7 +192,12 @@ def measure(kind, seconds: float, trace: bool, setup_s: float,
     `rec["iterations"]` and `rec["traced_iterations"]` are the loop
     iterations that the solves of the measured and of the traced
     window ran (the kind's `iterations`, read around each window),
-    and None for a kind that counts none, as `calls`."""
+    and None for a kind that counts none, as `calls`.
+
+    After both windows have closed, the trace is loaded once and read
+    twice: by `bench.trace.reduce`, and by the join of its ops to the
+    entry's compiled text (`bench.scopes.scope_times`), which gives
+    `rec["scope_s"]`. `bench/reading.py` lists every key of `rec`."""
     counter = _compile_counter()
     before = kind.iterations
     win = _window(kind, seconds, counter, log, "measured")
@@ -200,18 +220,27 @@ def measure(kind, seconds: float, trace: bool, setup_s: float,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": max(int(s.get("peak_bytes_in_use", 0))
                                        for s in stats)}
-    reduced = breakdown = None
+    reduced = breakdown = scope_s = None
     if trace:
         t = time.perf_counter()
-        reduced = tr.reduce_dir(str(TRACE_DIR))
+        pd = tr.load(str(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        ops = tr.device_ops(pd)
+        reduced = tr.reduce(pd, ops=ops)
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
         breakdown = {"device_ops": reduced.device_ops,
                      "idle_gaps": reduced.idle_gaps}
         log(f"trace reduced in {time.perf_counter() - t} s: "
             f"{reduced.kernel_events} kernel events")
+        t = time.perf_counter()
+        scope_s = _scope_seconds(kind, pd, ops)
+        del pd, ops
+        log(f"device seconds by name scope, in "
+            f"{time.perf_counter() - t} s: {scope_s}")
     nbytes, flops = kind.work()
+    iteration_bytes, iteration_flops = kind.iteration_work() or (None,
+                                                                 None)
     rec = {"unit": kind.unit, "requests": win.requests,
            "window_s": win.seconds, "setup_s": setup_s,
            "dispatch_s": win.dispatch_seconds,
@@ -219,9 +248,12 @@ def measure(kind, seconds: float, trace: bool, setup_s: float,
            "iterations": iterations,
            "traced_iterations": traced_iterations,
            "work_bytes": nbytes, "work_flops": flops,
+           "iteration_bytes": iteration_bytes,
+           "iteration_flops": iteration_flops,
            "peaks": peaks.peaks(device["kind"])
            if device["platform"] == "tpu" else None,
-           "trace": reduced}
+           "trace": reduced, "scope_s": scope_s,
+           "scope_work": kind.scope_work()}
     t = time.perf_counter()
     worst, failed = kind.check()
     log(f"check took {time.perf_counter() - t} s")
@@ -230,10 +262,22 @@ def measure(kind, seconds: float, trace: bool, setup_s: float,
                     breakdown=breakdown)
 
 
+def _scope_seconds(kind, pd, ops: dict) -> Optional[dict]:
+    """{fusion-group scope or `pad`: device seconds} in the traced
+    window, or None where the entry gives no compiled text or no op
+    joins to it."""
+    text = kind.hlo_text()
+    if text is None:
+        return None
+    t0, t1 = tr.window_ns(tr.bench_thread(pd))
+    return scopes.scope_times(pd, scopes.op_paths([text]), t0, t1, ops)
+
+
 def read_metrics(c: Cell, rec: dict, trace: bool) -> dict:
     out = {}
     for m in (c.per_layer if trace else c.end_to_end):
-        reader = load_module(BENCH / "metrics" / f"{m['name']}.py")
+        reader = load_module(c.root / "bench" / "metrics"
+                             / f"{m['name']}.py")
         value = reader.read(rec)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}

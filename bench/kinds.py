@@ -15,8 +15,12 @@ function. Nothing here knows a configuration by name.
   host.
   The configuration module gives `program(cfg, name)`, `inputs(cfg,
   name, seed, pool)`, `reference(cfg, name, seed, pool_indices)`,
-  `work(cfg, name)`, `reference_program(cfg, name, precision)` and
-  `LIMITS`.
+  `work(cfg, name)`, `reference_program(cfg, name, precision)`,
+  `LIMITS` and `SMALL` (the sizes a test run on the CPU can hold: a
+  dict merged over the configuration). It may give `scope_work(cfg,
+  name)`: {name scope: (bytes, flops)} that one call of the scope's
+  ops cannot do without, for a fusion group (`<program>.g<i>`) or
+  `pad` (`reading.scope_roofline_pct`).
 
 `solves`: one request is one solve, one `Executable.run` of the loop
   program `program`. The configuration's `program(cfg, name)` returns
@@ -35,7 +39,11 @@ function. Nothing here knows a configuration by name.
   how many of them ended in another status than `status`. The
   control (`reference_program`) returns a mapping with `x`,
   `iterations` and `status` (a `repro.guard.status` code), read
-  through the same accessor as the library's `SolverResult`.
+  through the same accessor as the library's `SolverResult`. The
+  configuration may give `iteration_work(cfg, name)`: the (bytes,
+  flops) that one loop iteration cannot do without
+  (`reading.iteration_roofline_pct`); its `scope_work` is per
+  iteration.
 
 `precision`, where given, puts the configuration's plain reference in
 the library's place, computed at that precision: `"highest"` is the
@@ -86,9 +94,11 @@ class Calls:
                                               int(traffic["pool"]))
         if precision is None:
             from repro import blas
-            self.entry = blas.compile(module.program(cfg, self.program),
-                                      tiles="default").run
+            self.exe = blas.compile(module.program(cfg, self.program),
+                                    tiles="default")
+            self.entry = self.exe.run
         else:
+            self.exe = None
             self.entry = module.reference_program(cfg, self.program,
                                                   precision)
         jax.block_until_ready((self.fixed, self.pool))
@@ -124,6 +134,34 @@ class Calls:
         """(bytes, flops) that one call cannot do without."""
         return self.module.work(self.cfg, self.program)
 
+    def _given(self, name: str):
+        """What the configuration's optional `name(cfg, program)` gives,
+        or None where it gives no such function."""
+        f = getattr(self.module, name, None)
+        return None if f is None else f(self.cfg, self.program)
+
+    def iteration_work(self) -> Optional[tuple]:
+        """A call runs no loop, so no iteration has work of its own."""
+        return None
+
+    def scope_work(self) -> Optional[dict]:
+        return self._given("scope_work")
+
+    def hlo_text(self) -> Optional[str]:
+        """The compiled HLO text of the library's entry at the pool's
+        first inputs, whose `op_name`s `bench.scopes` joins a trace's
+        ops to. None for the plain reference in the library's place,
+        and where `Executable.hlo_text` refuses the program (it takes
+        dataflow programs only)."""
+        if self.exe is None:
+            return None
+        try:
+            return self.exe.hlo_text(**self.fixed, **self.pool[0])
+        except TypeError as e:
+            if "hlo_text() is for dataflow programs" not in str(e):
+                raise
+            return None
+
     def check(self) -> tuple:
         """({"err": worst ‖out − ref‖ / ‖ref‖}, failed calls), over
         every output of every sampled call."""
@@ -153,6 +191,9 @@ class Solves(Calls):
         self.status = codes[name]
         super().__init__(cfg, module, traffic, seed, precision)
         self.iterations = 0
+
+    def iteration_work(self) -> Optional[tuple]:
+        return self._given("iteration_work")
 
     @staticmethod
     def _arrays(out) -> list:

@@ -146,7 +146,7 @@ def main(argv=None) -> int:
                   if ev.name == tr.WINDOW)
     exe = kind.entry.__self__       # the bound `Executable.run`
     paths = scopes.op_paths([exe.hlo_text(**kind.fixed, **kind.pool[0])])
-    pad_scope_s = scopes.scope_seconds(pd, paths, "pad", t0, t1)
+    times = scopes.scope_times(pd, paths, t0, t1)
 
     out = {"workload": args.workload, "seed": args.seed,
            "record": args.record, "setup_s": setup_s,
@@ -163,8 +163,9 @@ def main(argv=None) -> int:
                                                      t1=setup_end)
     out["pad_us.call"] = (1e6 * scopes.pad_seconds(red.device_ops)
                           / traced.requests)
-    if pad_scope_s is not None:
-        out["pad_scope_us.call"] = 1e6 * pad_scope_s / traced.requests
+    if times is not None:
+        out["pad_scope_us.call"] = (1e6 * times.get("pad", 0.0)
+                                    / traced.requests)
     out["device_idle.call"] = 100.0 * (1.0 - red.busy_s / red.window_s)
     out["traced_calls"] = traced.requests
     out["device_ops"] = red.device_ops

@@ -12,19 +12,21 @@ joining its instruction name, within the HLO module running it, to the
 compiled text (`repro.blas.Executable.hlo_text`).
 
     paths = op_paths([exe.hlo_text(**inputs)])
-    seconds = scope_seconds(pd, paths, "pad", t0_ns, t1_ns)
+    times = scope_times(pd, paths, t0_ns, t1_ns)  # {"mvt.g0": s, "pad": s}
 
-`scope_seconds` takes the union of the scoped ops' intervals, so ops
-that overlap count once (where `bench.trace.self_times` would take one
-op's time out of another's). `pad_seconds` needs no raw trace: it sums
-the self time of the `pad` ops that `bench.trace.reduce` keeps, which
-is what the benchmark's `pad_us.call` reads. The pads do not overlap
-one another, so the two agree where every pad is among the ten ops
-kept.
+`scope_times` gives every fusion-group scope and `pad` the union of
+its ops' intervals, so ops that overlap count once (where
+`bench.trace.self_times` would take one op's time out of another's);
+a pad runs inside its group, so it counts in both. `pad_seconds`
+needs no raw trace: it sums the self time of the `pad` ops that
+`bench.trace.reduce` keeps, which is what the benchmark's `pad_us.call`
+reads. The pads do not overlap one another, so the two agree where
+every pad is among the ten ops kept.
 """
 from __future__ import annotations
 
 import bisect
+import collections
 import re
 from typing import Dict, Iterable, Optional
 
@@ -40,6 +42,8 @@ _INSTR = re.compile(r"^%?([\w.\-]+)(?: = |$)")
 # a pad, or a fusion of nothing but pads and bitcasts (XLA names a
 # fusion after its ops, producers first: `pad_dot_fusion` is a dot)
 _PAD = re.compile(r"^pad(?:_(?:pad|bitcast))*(?:_fusion)?(?:\.\d+)?$")
+# a fusion group's name scope (`repro.core.codegen.group_key`)
+_GROUP = re.compile(r"^.+\.g\d+$")
 
 
 def op_paths(hlo_texts: Iterable[str]) -> Dict[str, Dict[str, str]]:
@@ -103,21 +107,30 @@ def op_path(ev: tr.Event, paths: dict, module: Optional[str]
     return paths.get(str(mod), {}).get(str(instr))
 
 
-def scope_seconds(pd, paths: dict, scope: str, t0: float, t1: float,
-                  device_ops: Optional[dict] = None) -> Optional[float]:
-    """Seconds in [t0, t1] (ns) during which an op under name scope
-    `scope` ran: the union of their intervals, mean over the devices
-    that ran ops. None where no op of the window joins to a path.
-    `device_ops` is `bench.trace.device_ops(pd)` where the caller has
-    it already (reading the ops' stats is most of the cost)."""
+def _layer_scopes(path: str) -> list:
+    """The fusion-group scopes and `pad` among an op path's names."""
+    return [n for n in set(path.split("/")) if n == "pad" or _GROUP.match(n)]
+
+
+def scope_times(pd, paths: dict, t0: float, t1: float,
+                device_ops: Optional[dict] = None
+                ) -> Optional[Dict[str, float]]:
+    """{scope: seconds} in [t0, t1] (ns) during which an op under each
+    fusion-group scope (`<program>.g<i>`) or `pad` ran: the union of
+    their intervals, mean over the devices that ran ops. None where no
+    op of the window joins to a path. `device_ops` is
+    `bench.trace.device_ops(pd)` where the caller has it already
+    (reading the ops' stats is most of the cost)."""
     modules = _modules(pd)
     if device_ops is None:
         device_ops = tr.device_ops(pd)
-    per_device, joined = [], False
+    total: Dict[str, float] = collections.defaultdict(float)
+    scopes_of: Dict[str, list] = {}      # a window repeats few paths
+    joined = False
     for plane, ops in device_ops.items():
         runs = modules.get(plane, [])
         starts = [r[0] for r in runs]
-        spans = []
+        spans = collections.defaultdict(list)
         for ev in ops:
             if ev.end_ns <= t0 or ev.start_ns >= t1:
                 continue
@@ -125,10 +138,15 @@ def scope_seconds(pd, paths: dict, scope: str, t0: float, t1: float,
             if path is None:
                 continue
             joined = True
-            if scope in path.split("/"):
-                spans.append((ev.start_ns, ev.end_ns))
-        per_device.append(sum(e - s for s, e in
-                              tr.union(tr.clip(spans, t0, t1))))
+            if path not in scopes_of:
+                scopes_of[path] = _layer_scopes(path)
+            for scope in scopes_of[path]:
+                spans[scope].append((ev.start_ns, ev.end_ns))
+        for scope, intervals in spans.items():
+            total[scope] += sum(e - s for s, e in
+                                tr.union(tr.clip(intervals, t0, t1)))
     if not joined:
         return None
-    return sum(per_device) / len(per_device) * 1e-9
+    return {scope: ns / len(device_ops) * 1e-9
+            for scope, ns in total.items()}
+

@@ -26,6 +26,9 @@ from bench import gen, numerics
 # CPU; and the solves that did not end in the traffic's status
 LIMITS = {"err": 6e-6, "unconverged": 0}
 
+# the configuration is at a size a test run holds already
+SMALL: dict = {}
+
 M_STREAM, B_STREAM = 1, 2
 
 
@@ -95,3 +98,13 @@ def work(cfg, name) -> tuple:
     panel read and x written, one product with A."""
     n, s = cfg["n"], cfg["s"][name]
     return n * n * 4 + 2 * n * s * 4, 2 * n * n * s
+
+
+def iteration_work(cfg, name) -> tuple:
+    """(bytes, flops) one CG iteration cannot do without, for each of
+    the s columns: A read once for the panel's product q = A p; x, r
+    and p each read and written once (q and the step lengths stay on
+    the chip); 2n² flops for the product, and 2n each for p·q, x + αp,
+    r − αq, r·r and r + βp."""
+    n, s = cfg["n"], cfg["s"][name]
+    return n * n * 4 + 6 * n * s * 4, 2 * n * n * s + 10 * n * s

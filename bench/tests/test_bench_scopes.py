@@ -15,7 +15,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import harness, scopes  # noqa: E402
-from test_bench_harness import SMALL, small_cell  # noqa: E402
+from test_bench_harness import small_cell, small_sizes  # noqa: E402
 
 
 @dataclasses.dataclass
@@ -83,13 +83,29 @@ def test_pad_scope_takes_the_union_of_overlapping_pad_ops():
                      _tpu_op("pad.21", 950, 960)])
     paths = scopes.op_paths([HLO])
     # 100..500 and 950..960, where the sum of durations would be 510
-    assert scopes.scope_seconds(pd, paths, "pad", 0, 1000) == \
+    assert scopes.scope_times(pd, paths, 0, 1000)["pad"] == \
         pytest.approx(410e-9)
-    assert scopes.scope_seconds(pd, paths, "pad", 200, 1000) == \
+    assert scopes.scope_times(pd, paths, 200, 1000)["pad"] == \
         pytest.approx(310e-9)
     # a group's scope takes its pad, its kernel and its slice
-    assert scopes.scope_seconds(pd, paths, "gesummv.g0", 0, 1000) == \
+    assert scopes.scope_times(pd, paths, 0, 1000)["gesummv.g0"] == \
         pytest.approx((400 - 100 + 900 - 500 + 10) * 1e-9)
+
+
+def test_scope_times_gives_each_group_and_pad_its_union_on_each_device():
+    one = [_tpu_op("pad.21", 100, 400), _tpu_op("gemv", 400, 900),
+           _tpu_op("pad.20", 950, 990)]
+    two = [_tpu_op("pad.21", 0, 100), _tpu_op("slice_reduce_fusion",
+                                             100, 300)]
+    pd = Profile([Plane(f"/device:TPU:{i}", [
+        Line("XLA Modules", [Ev("jit_program(3202498600)", 0, 1000)]),
+        Line("XLA Ops", ops)]) for i, ops in enumerate((one, two))])
+    times = scopes.scope_times(pd, scopes.op_paths([HLO]), 0, 1000)
+    # the mean over both devices, the pad counted in its group as well
+    assert times == pytest.approx({"gesummv.g0": (800 + 300) / 2 * 1e-9,
+                                   "gesummv.g1": 40 / 2 * 1e-9,
+                                   "pad": (340 + 100) / 2 * 1e-9})
+    assert scopes.scope_times(pd, {}, 0, 1000) is None
 
 
 def test_pad_us_reads_the_pads_self_time_per_traced_call():
@@ -111,13 +127,13 @@ def test_pad_us_reads_the_pads_self_time_per_traced_call():
 
 def test_scope_seconds_is_none_where_no_op_joins():
     pd = _tpu_trace([_tpu_op("pad.21", 100, 400)])
-    assert scopes.scope_seconds(pd, {}, "pad", 0, 1000) is None
+    assert scopes.scope_times(pd, {}, 0, 1000) is None
     other = {"jit_other": scopes.op_paths([HLO])["jit_program"]}
-    assert scopes.scope_seconds(pd, other, "pad", 0, 1000) is None
-    # joined, but nothing under the scope: zero, not None
+    assert scopes.scope_times(pd, other, 0, 1000) is None
+    # joined, but nothing under the scope: no entry, not None
     gemv = _tpu_trace([_tpu_op("gemv", 100, 400)])
-    assert scopes.scope_seconds(gemv, scopes.op_paths([HLO]), "pad", 0,
-                                1000) == 0.0
+    times = scopes.scope_times(gemv, scopes.op_paths([HLO]), 0, 1000)
+    assert "pad" not in times and times["gesummv.g0"] > 0
 
 
 def test_cpu_ops_join_by_their_instruction_and_module_stats():
@@ -127,7 +143,7 @@ def test_cpu_ops_join_by_their_instruction_and_module_stats():
         Ev("gemv", 60, 40, {"hlo_op": "gemv",
                             "hlo_module": "jit_program"})])])])
     paths = scopes.op_paths([HLO])
-    assert scopes.scope_seconds(pd, paths, "pad", 0, 1000) == \
+    assert scopes.scope_times(pd, paths, 0, 1000)["pad"] == \
         pytest.approx(50e-9)
 
 
@@ -151,8 +167,9 @@ def test_a_recorded_cpu_trace_attributes_device_time_to_groups(tmp_path):
                         recursive=True)
     pd = ProfileData.from_file(path)
     inf = float("inf")
+    times = scopes.scope_times(pd, paths, 0, inf)
     for group in ("gesummv.g0", "gesummv.g1"):
-        assert scopes.scope_seconds(pd, paths, group, 0, inf) > 0
+        assert times[group] > 0
 
 
 @pytest.fixture
@@ -163,7 +180,7 @@ def small_cells(monkeypatch):
 
     def small(name):
         c = cell(name)
-        c.config = {**c.config, **SMALL[c.config["name"]]}
+        c.config = {**c.config, **small_sizes(c.module)}
         return c
 
     monkeypatch.setattr(harness, "cell", small)

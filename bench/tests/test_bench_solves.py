@@ -73,6 +73,8 @@ def test_a_solve_cell_is_correct_and_counts_its_iterations(program):
     assert m.rec["iterations"] == sum(int(o.iterations) for o in sent)
     assert m.rec["iterations"] >= m.rec["requests"]
     assert m.rec["traced_iterations"] is None
+    assert (m.rec["iteration_bytes"], m.rec["iteration_flops"]) == \
+        c.module.iteration_work(c.config, program)
     assert line["metrics"]["calls_per_s"]["value"] == pytest.approx(
         m.rec["requests"] / m.rec["window_s"])
     assert line["metrics"]["setup_s"]["value"] == 1.0
@@ -98,6 +100,8 @@ def test_a_traced_solve_window_counts_its_own_iterations(program, tmp_path,
     # the unit-aware readers read a solve cell as they read calls
     assert 0 <= line["metrics"]["device_idle.call"]["value"] < 100
     assert line["metrics"]["dispatch_us"]["value"] > 0
+    # `Executable.hlo_text` takes no loop program: no scope is timed
+    assert m.rec["scope_s"] is None
 
 
 def _perturbed(entry):
@@ -193,3 +197,52 @@ def test_calls_count_no_iterations():
     assert line["correct"], line["checks"]
     assert m.rec["iterations"] is None
     assert m.rec["traced_iterations"] is None
+    assert m.rec["iteration_bytes"] is None
+    assert m.rec["iteration_flops"] is None
+
+
+@pytest.mark.parametrize("program, want", [
+    # A (96 x 96) read once; x, r, p (96 x s) each read and written;
+    # the product's 2 n² s flops and 10 n s for the two dots and three
+    # vector updates
+    ("cg", (96 * 96 * 4 + 6 * 96 * 4, 2 * 96 * 96 + 10 * 96)),
+    ("block_cg", (96 * 96 * 4 + 6 * 96 * 4 * 4,
+                  2 * 96 * 96 * 4 + 10 * 96 * 4))])
+def test_iteration_work_counts_the_least_bytes_of_one_iteration(program,
+                                                                want):
+    c = solve_cell(program)
+    assert c.module.iteration_work(c.config, program) == want
+
+
+def test_a_configuration_may_give_no_iteration_work_and_scope_work(
+        monkeypatch):
+    c = solve_cell("cg")
+    monkeypatch.delattr(c.module, "iteration_work")
+    m, _ = measured(c, harness.prepare(c, SEED))
+    assert m.rec["iteration_bytes"] is None
+    assert m.rec["iteration_flops"] is None
+    assert m.rec["scope_work"] is None
+    work = {"cg_matvec.g0": (96 * 96 * 4, 2 * 96 * 96)}
+    monkeypatch.setattr(c.module, "scope_work",
+                        lambda cfg, name: work, raising=False)
+    m, _ = measured(c, harness.prepare(c, SEED))
+    assert m.rec["scope_work"] == work
+
+
+def test_only_hlo_texts_refusal_of_a_loop_program_reads_as_no_text():
+    c = solve_cell("cg")
+    kind = harness.prepare(c, SEED)
+    with pytest.raises(TypeError, match="for dataflow programs"):
+        kind.exe.hlo_text(**kind.fixed, **kind.pool[0])
+    assert kind.hlo_text() is None
+
+    class Broken:
+        @staticmethod
+        def hlo_text(**inputs):
+            raise TypeError("an input of the wrong type")
+
+    kind.exe = Broken()
+    with pytest.raises(TypeError, match="wrong type"):
+        kind.hlo_text()
+    kind.exe = None                 # the plain reference in its place
+    assert kind.hlo_text() is None

@@ -31,7 +31,7 @@ import glob
 import os
 import re
 import warnings
-from typing import Callable, Iterable, Mapping, Sequence
+from typing import Callable, Iterable, Mapping, Optional, Sequence
 
 WINDOW = "bench.window"
 TOP = 10
@@ -194,12 +194,21 @@ def label_at(t_ns: float, host: Sequence[Event]) -> str:
     return f"{mark.name} / {inner.name}"
 
 
-def reduce(pd, is_kernel: Callable[[Event], bool] = is_pallas_kernel
-           ) -> Reduced:
-    host = bench_thread(pd)
+def window_ns(host: Sequence[Event]) -> tuple:
+    """(start, end) of the `bench.window` annotation among the bench
+    thread's events (`bench_thread`)."""
     window = next(ev for ev in host if ev.name == WINDOW)
-    t0, t1 = window.start_ns, window.end_ns
-    per_device = device_ops(pd)
+    return window.start_ns, window.end_ns
+
+
+def reduce(pd, is_kernel: Callable[[Event], bool] = is_pallas_kernel,
+           ops: Optional[dict] = None) -> Reduced:
+    """The reduction of a loaded trace; `ops` is `device_ops(pd)` where
+    the caller has it already (reading the ops' stats is most of the
+    cost)."""
+    host = bench_thread(pd)
+    t0, t1 = window_ns(host)
+    per_device = device_ops(pd) if ops is None else ops
     if not per_device:
         raise ValueError("the trace holds no device operations")
     busy, kernels, self_ns = [], 0, collections.Counter()
@@ -222,7 +231,3 @@ def reduce(pd, is_kernel: Callable[[Event], bool] = is_pallas_kernel
                     for name, ns in self_ns.most_common(TOP)],
         idle_gaps=[[label_at(0.5 * (s + e), host), (e - s) * 1e-9]
                    for s, e in gaps[:TOP]])
-
-
-def reduce_dir(log_dir: str) -> Reduced:
-    return reduce(load(log_dir))
